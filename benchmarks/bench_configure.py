@@ -55,8 +55,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import tempfile
 import time
 
 import numpy as np
@@ -333,9 +331,8 @@ def _bench_rescore(w, spec, bw, conf):
 def bench_huge(args) -> None:
     """The ISSUE 6 scaling curve + gates; writes the ``--json`` artifact."""
     import jax
-    cache_dir = args.jax_cache_dir or os.path.join(
-        tempfile.gettempdir(), "repro-jax-cache")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     sizes = [int(s) for s in args.sizes.split(",")] if args.sizes else \
@@ -455,9 +452,6 @@ def main() -> None:
     ap.add_argument("--json", default=None,
                     help="with --huge: write the scaling-curve artifact "
                          "to this path")
-    ap.add_argument("--jax-cache-dir", default=None,
-                    help="with --huge: persistent XLA compilation cache "
-                         "directory (default: a tempdir location)")
     args = ap.parse_args()
 
     if args.huge:

@@ -15,12 +15,13 @@ engines:
   re-score amortises and Python dispatch would dominate).
 
 Because the RNG stream lives entirely in the MovePlan and both engines
-score bit-identically (float64 everywhere, matching reduction order), the
-two backends produce **byte-identical plans** chain for chain — pinned by
-``tests/test_backend_determinism.py``.  ``backend=None`` (the default) is
-not handled here at all: ``run_search`` keeps the historical per-candidate
-``anneal``/``anneal_multistart`` path, bit-exact with its regression
-fixtures.
+score bit-identically on CPU (float64 everywhere, matching reduction
+order), the two backends produce **byte-identical plans** there, chain
+for chain — pinned by ``tests/test_backend_determinism.py``.  On TPU the
+JAX scores are within ``jax_engine.TPU_REL_TOL`` of the reference.
+``backend=None`` (the default) is not handled here at all: ``run_search``
+keeps the historical per-candidate ``anneal``/``anneal_multistart`` path,
+bit-exact with its regression fixtures.
 
 Scale comes from the *hierarchical* mode layered on top: nodes are
 clustered into tier/bandwidth islands (:func:`build_islands`), the
@@ -374,8 +375,7 @@ def dedicate_candidates(survivors: Sequence[Conf],
                         profiles: Sequence[Profile],
                         sa_idx: Sequence[int], bw: np.ndarray,
                         spec: ClusterSpec, budget, seed: int, *,
-                        compute_aware: bool = True,
-                        kernels: str = "auto") -> Dict[int, SAResult]:
+                        compute_aware: bool = True) -> Dict[int, SAResult]:
     """Stage-5 dedication through the unified backend-selectable core.
 
     Runs SA dedication for the survivor indices in ``sa_idx`` and returns
@@ -448,8 +448,7 @@ def dedicate_candidates(survivors: Sequence[Conf],
             from .jax_engine import JaxDedicationEngine
             jeng = JaxDedicationEngine([survivors[i] for i in idxs],
                                        [profiles[i] for i in idxs], bw,
-                                       spec, kernels=kernels,
-                                       compute_aware=compute_aware,
+                                       spec, compute_aware=compute_aware,
                                        pairs=pairs,
                                        device_pairs=device_pairs)
             device_pairs = jeng.device_pairs

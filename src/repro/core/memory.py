@@ -28,7 +28,7 @@ import numpy as np
 from ..models.config import ModelConfig
 from . import flops as F
 from .cluster import ClusterSpec
-from .mlp import mlp_forward_jit, pad_batch_rows
+from .mlp import mlp_forward_rows, pad_batch_rows
 from .partition import Partition, uniform_partition
 from .simulator import Conf, Workload, ring_kv_block_bytes
 
@@ -294,11 +294,11 @@ class MemoryEstimator:
                       confs: Sequence[Conf]) -> np.ndarray:
         """Predicted peak bytes/GPU for many configurations at once.
 
-        One jitted :func:`~repro.core.mlp.mlp_forward_jit` call on the whole
+        One jitted :func:`~repro.core.mlp.mlp_forward_rows` call on the whole
         ``(N, F)`` feature matrix (zero-padded to a power-of-two row bucket so
-        varying candidate-set sizes reuse a handful of XLA traces).  Row ``i``
-        is bit-identical to ``predict(cfg, confs[i])`` — the scalar API is a
-        one-row special case of this path.
+        varying candidate-set sizes reuse a handful of XLA traces).  That
+        forward computes each row on its own, so row ``i`` is bit-identical
+        to ``predict(cfg, confs[i])``, the one-row case of this path.
 
         Args:
             cfg: model configuration shared by every candidate.
@@ -317,7 +317,7 @@ class MemoryEstimator:
         x = (_features_batch(cfg, confs, with_cp=self.with_cp)
              - self.x_mean) / self.x_std
         xb = pad_batch_rows(x.astype(np.float32))
-        out = mlp_forward_jit(self.params, jnp.asarray(xb))
+        out = mlp_forward_rows(self.params, jnp.asarray(xb))
         y = np.asarray(out[:len(confs), 0], np.float64)
         pred = np.exp(y * self.y_std + self.y_mean)
         if self.residual:

@@ -27,21 +27,26 @@ def mlp_forward(params, x):
     return x
 
 
-# Jitted forward shared by every estimator instance.  jax.jit caches one
-# trace per (param tree structure, batch shape); callers that pad batches to
-# power-of-two buckets therefore hit a handful of traces total, and repeated
-# ``configure()`` calls reuse them instead of re-tracing per candidate.
-mlp_forward_jit = jax.jit(mlp_forward)
+@jax.jit
+def mlp_forward_rows(params, x):
+    """Forward of each row of ``x`` on its own: one ``lax.map`` step per
+    row, so row ``i`` of the output depends on ``x[i]`` alone and is
+    bit-identical whatever else is in the batch — a one-row batch is the
+    same computation.  A batched matmul gives no such guarantee: XLA tiles
+    it by batch size, and rounding then varies with the row count.
+
+    Shared by every estimator instance: jax.jit caches one trace per
+    (param tree structure, batch shape); callers that pad batches to
+    power-of-two buckets therefore hit a handful of traces total."""
+    return jax.lax.map(lambda row: mlp_forward(params, row), x)
 
 
 def pad_batch_rows(x: np.ndarray, minimum: int = 8) -> np.ndarray:
     """Zero-pad ``x`` along axis 0 to the next power-of-two row count.
 
-    Bounds the number of distinct batch shapes :data:`mlp_forward_jit` ever
-    sees (log2 of the largest batch), so candidate-set sizes that vary from
-    call to call do not each pay an XLA retrace.  Row ``i`` of the padded
-    forward is bit-identical to row ``i`` of the unpadded one (row-wise
-    independence of the matmuls).
+    Bounds the number of distinct batch shapes :func:`mlp_forward_rows`
+    ever sees (log2 of the largest batch), so candidate-set sizes that vary
+    from call to call do not each pay an XLA retrace.
 
     Args:
         x: ``(n, f)`` feature matrix.

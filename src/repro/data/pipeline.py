@@ -27,12 +27,21 @@ def _mix(*xs: int) -> np.random.Generator:
 
 @dataclass(frozen=True)
 class SyntheticCorpus:
+    """Order-2 Markov sequences, each with its own transition rule.
+
+    ``n_sequences`` bounds the corpus: sequence ``idx`` is sequence
+    ``idx % n_sequences``, so later steps revisit earlier data (``None``:
+    every index is a new sequence).
+    """
     vocab_size: int
     seed: int = 0
     noise: float = 0.05
+    n_sequences: Optional[int] = None
 
     def sequence(self, idx: int, length: int) -> np.ndarray:
         """Deterministic order-2 Markov sequence #idx."""
+        if self.n_sequences is not None:
+            idx %= self.n_sequences
         rng = _mix(self.seed, idx)
         v = self.vocab_size
         a = int(rng.integers(1, v))

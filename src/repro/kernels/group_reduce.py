@@ -3,28 +3,52 @@
 The vmapped SA core spends its inner step reducing many small gathered
 bandwidth sub-matrices — per communicator group, the min link bandwidth
 turned into a slowdown scale (TP / CP groups), and per pipeline stage the
-max member compute slowdown.  Both reductions are fused here as Pallas
-kernels: one VMEM-resident ``(block, m, m)`` (or ``(block, m)``) tile per
-grid step, reduced and rescaled without materialising the masked
-intermediates the pure-jnp path creates.
+max member compute slowdown.  Both reductions are Pallas TPU kernels here,
+laid out the way Mosaic tiles a vreg: the group index runs along the
+128-wide lane axis (padded to a lane multiple), the small per-group axis
+(``m·m`` link entries, or the stage's members) runs down the sublanes and
+is reduced, and each grid step writes one lane-dense ``(1, block)`` row.
+The kernels compute in float32 (a TPU has no native float64); the
+reference bandwidth of the slowdown scale is an SMEM scalar.
 
-Each kernel has a pure-jnp reference (``*_ref``) computing the identical
-values with the identical elementwise ops — min and max are
-order-insensitive and the divide is elementwise, so the Pallas output is
-bit-equal to the reference on every backend (pinned by
-``tests/test_jax_engine.py``).  On CPU the kernels run under
-``interpret=True``; native TPU lowering would want f32 inputs and
-(8, 128)-aligned tiles, which the tiny group sizes here do not provide —
-the scorer therefore defaults to the reference path off-TPU (see
-``repro.core.jax_engine``).
+Each kernel has a pure-jnp reference (``*_ref``) in the caller's dtype.
+Rounding to float32 is monotonic, so a kernel's min or max is exactly the
+float32 rounding of the reference's: equal for float32 inputs, within one
+float32 rounding (relative ``2**-24``) for float64 ones.  The scale adds
+one float32 rounding of ``ref_bw`` and one of the divide, so it sits
+within ``3 * 2**-24`` relative of the reference.  ``tests/test_jax_engine``
+pins both bounds.  The scorer (``repro.core.jax_engine``) runs the kernels
+on TPU and the references elsewhere; ``interpret=True`` runs a kernel in
+the Pallas interpreter, which only tests ask for.
 """
 from __future__ import annotations
 
 import functools
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+#: Lane width of one grid step; at most 64 x 1024 float32 (256 KiB) of
+#: VMEM per input buffer for the largest (tp = 8) groups.
+BLOCK_LANES = 1024
+#: Row tile of the group-max reduction, which accumulates over row blocks
+#: when a stage has more members than this.
+BLOCK_ROWS = 1024
+
+# Block indices must be int32 for Mosaic, also when the caller traces
+# under x64 (where a bare ``0`` would lower as int64).
+_ZERO = np.int32(0)
+
+
+def _lane_layout(n: int):
+    """Padded lane count and lane block for ``n`` groups."""
+    padded = -(-n // LANES) * LANES
+    block = min(padded, BLOCK_LANES)
+    return -(-padded // block) * block, block
 
 
 # ---------------------------------------------------------------------------
@@ -49,36 +73,35 @@ def group_min_scale_ref(sub: jax.Array, ref_bw) -> jax.Array:
     return jnp.where(ok, ref_bw / gbw, 1.0)
 
 
-def _min_scale_kernel(sub_ref, refbw_ref, o_ref):
-    sub = sub_ref[...]
-    gbw = sub.min(axis=(1, 2))
-    ok = jnp.isfinite(gbw) & (gbw > 0)
+def _min_scale_kernel(refbw_ref, sub_ref, o_ref):
+    gbw = jnp.min(sub_ref[...], axis=0, keepdims=True)
+    ok = (gbw > 0) & (gbw < jnp.inf)
     o_ref[...] = jnp.where(ok, refbw_ref[0] / gbw, 1.0)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def group_min_scale(sub: jax.Array, ref_bw, *, block: int = 128,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def group_min_scale(sub: jax.Array, ref_bw, *,
                     interpret: bool = False) -> jax.Array:
-    """Pallas version of :func:`group_min_scale_ref` (bit-equal output)."""
+    """Pallas TPU version of :func:`group_min_scale_ref`, in float32."""
     n, m, _ = sub.shape
-    b = min(block, n)
-    pad = (-n) % b
-    if pad:
-        # padded groups reduce to an all-inf min -> masked to scale 1.0,
-        # then sliced away
-        sub = jnp.pad(sub, ((0, pad), (0, 0), (0, 0)),
-                      constant_values=jnp.inf)
-    refbw = jnp.full((1,), ref_bw, dtype=sub.dtype)
+    padded, block = _lane_layout(n)
+    # (m*m, n): groups on lanes; padded groups reduce to an all-inf min,
+    # are masked to scale 1.0, then sliced away
+    x = sub.reshape(n, m * m).T.astype(jnp.float32)
+    x = jnp.pad(x, ((0, 0), (0, padded - n)), constant_values=jnp.inf)
+    refbw = jnp.full((1,), ref_bw, jnp.float32)
     out = pl.pallas_call(
         _min_scale_kernel,
-        grid=((n + pad) // b,),
-        in_specs=[pl.BlockSpec((b, m, m), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((1,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n + pad,), sub.dtype),
+        grid=(padded // block,),
+        in_specs=[pl.BlockSpec((1,), lambda i: (_ZERO,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((m * m, block), lambda i: (_ZERO, i))],
+        out_specs=pl.BlockSpec((1, block), lambda i: (_ZERO, i)),
+        out_shape=jax.ShapeDtypeStruct((1, padded), jnp.float32),
         interpret=interpret,
-    )(sub, refbw)
-    return out[:n]
+        name="group_min_scale",
+    )(refbw, x)
+    return out[0, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -92,25 +115,38 @@ def group_max_ref(vals: jax.Array) -> jax.Array:
 
 
 def _max_kernel(v_ref, o_ref):
-    o_ref[...] = v_ref[...].max(axis=1)
+    part = jnp.max(v_ref[...], axis=0, keepdims=True)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        o_ref[...] = part
+
+    @pl.when(pl.program_id(1) > 0)
+    def _():
+        o_ref[...] = jnp.maximum(o_ref[...], part)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def group_max(vals: jax.Array, *, block: int = 128,
-              interpret: bool = False) -> jax.Array:
-    """Pallas version of :func:`group_max_ref` (bit-equal output)."""
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def group_max(vals: jax.Array, *, interpret: bool = False) -> jax.Array:
+    """Pallas TPU version of :func:`group_max_ref`, in float32."""
     n, m = vals.shape
-    b = min(block, n)
-    pad = (-n) % b
-    if pad:
-        vals = jnp.pad(vals, ((0, pad), (0, 0)),
-                       constant_values=-jnp.inf)
+    padded, block = _lane_layout(n)
+    rows = min(m, BLOCK_ROWS)
+    m_pad = -(-m // rows) * rows
+    # (m, n): rows on lanes, members reduced down the sublanes; padding is
+    # -inf, the identity of max
+    x = vals.T.astype(jnp.float32)
+    x = jnp.pad(x, ((0, m_pad - m), (0, padded - n)),
+                constant_values=-jnp.inf)
     out = pl.pallas_call(
         _max_kernel,
-        grid=((n + pad) // b,),
-        in_specs=[pl.BlockSpec((b, m), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n + pad,), vals.dtype),
+        grid=(padded // block, m_pad // rows),
+        in_specs=[pl.BlockSpec((rows, block), lambda i, k: (k, i))],
+        out_specs=pl.BlockSpec((1, block), lambda i, k: (_ZERO, i)),
+        out_shape=jax.ShapeDtypeStruct((1, padded), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(vals)
-    return out[:n]
+        name="group_max",
+    )(x)
+    return out[0, :n]

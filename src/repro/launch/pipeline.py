@@ -44,6 +44,9 @@ def pipeline_loss_fn(embed_fn: Callable, stage_fn: Callable,
         n_mb = tokens_mb.shape[0]
         stages_local = jax.tree.map(lambda a: a[0], stages_local)
         sfn = jax.checkpoint(stage_fn) if remat else stage_fn
+        # the head's logits are the largest activation of a tick: recompute
+        # them in the backward pass instead of keeping one set per tick
+        hfn = jax.checkpoint(head_loss_fn) if remat else head_loss_fn
         ticks = n_mb + pp - 1
         perm = [(i, (i + 1) % pp) for i in range(pp)]
 
@@ -64,7 +67,7 @@ def pipeline_loss_fn(embed_fn: Callable, stage_fn: Callable,
             valid = (idx == pp - 1) & (mb_out >= 0) & (mb_out < n_mb)
             mb_loss = jax.lax.cond(
                 valid,
-                lambda: head_loss_fn(shared, out, lbl),
+                lambda: hfn(shared, out, lbl),
                 lambda: jnp.zeros((), jnp.float32))
             loss_sum = loss_sum + mb_loss
             state = jax.lax.ppermute(out, axis, perm)
@@ -91,3 +94,44 @@ def pipeline_loss_fn(embed_fn: Callable, stage_fn: Callable,
                        labels_mb)
 
     return loss
+
+
+def model_pipeline_loss(cfg, mesh: Mesh, *, axis: str = "pipe",
+                        data_axis: str = ""):
+    """:func:`pipeline_loss_fn` over the model stack's own embedding, layer
+    scan and loss head, for params laid out by :func:`model_stage_params`.
+
+    Every stage runs ``n_layers / pp`` layers of one uniform stack, so the
+    config must be a dense decoder without per-layer attention variants.
+    """
+    from ..models import model as M
+    from ..models.sharding import ShardCtx
+    from ..models.transformer import run_stack
+
+    if cfg.family != "dense" or cfg.local_global_period or cfg.sliding_window:
+        raise NotImplementedError(
+            f"pipeline stages need a uniform dense stack, got {cfg.name}")
+    stage_cfg = cfg.replace(n_layers=cfg.n_layers // mesh.shape[axis])
+
+    def embed_fn(shared, tokens):
+        return M.embed_inputs(shared, cfg, tokens)[0]
+
+    def stage_fn(stage, x):
+        b, s, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        return run_stack(x, {"layers": stage}, stage_cfg, ShardCtx(),
+                         positions)[0]
+
+    def head_loss_fn(shared, x, labels):
+        return M.head_loss(shared, cfg, x, labels)
+
+    return pipeline_loss_fn(embed_fn, stage_fn, head_loss_fn, mesh,
+                            axis=axis, data_axis=data_axis)
+
+
+def model_stage_params(params, pp: int):
+    """Model params -> ``{"stages": (pp, L/pp, ...) layers, "shared": the
+    rest}``, the layout :func:`model_pipeline_loss` takes."""
+    shared = {k: v for k, v in params.items() if k != "layers"}
+    return {"stages": stage_params_split(params["layers"], pp),
+            "shared": shared}

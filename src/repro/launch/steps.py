@@ -17,8 +17,11 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, opt: AdamW,
                     n_micro: int = 1):
     """Microbatch-accumulation training step (Pipette's bs_micro knob).
 
-    grads accumulate in fp32 across a lax.scan over n_micro microbatches
-    (each fwd+bwd under remat), then one AdamW update."""
+    grads accumulate in fp32 across n_micro microbatches (each fwd+bwd
+    under remat), then one AdamW update.  The microbatch loop is unrolled:
+    as a while loop its fp32 accumulator carry is double-buffered, which
+    for 12-layer gpt-1.1b on one 16 GB TPU v5e costs 1.4 GiB of the
+    15.75 GiB the step may use."""
 
     def train_step(params, opt_state: AdamWState, batch: Dict[str, Any]):
         def micro_loss(p, mb):
@@ -43,7 +46,8 @@ def make_train_step(cfg: ModelConfig, ctx: ShardCtx, opt: AdamW,
 
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                  params)
-            (gsum, lsum), _ = jax.lax.scan(micro_step, (zeros, 0.0), mbs)
+            (gsum, lsum), _ = jax.lax.scan(micro_step, (zeros, 0.0), mbs,
+                                           unroll=True)
             grads = jax.tree.map(lambda g: g / n_micro, gsum)
             loss = lsum / n_micro
 

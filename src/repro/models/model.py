@@ -23,7 +23,8 @@ from .sharding import ShardCtx
 from .transformer import (_proj_qkv, attn_block, init_params, layer_plan,
                           mlp_block, run_stack)
 
-__all__ = ["init_params", "forward_logits", "loss_fn", "prefill",
+__all__ = ["init_params", "forward_logits", "loss_fn", "head_loss",
+           "embed_inputs", "prefill",
            "init_cache", "decode_step", "cache_pspecs"]
 
 
@@ -68,7 +69,19 @@ def loss_fn(params, cfg: ModelConfig, ctx: ShardCtx, batch) -> Tuple[jax.Array, 
     """Mean next-token CE over valid labels (labels < 0 are masked)."""
     logits = forward_logits(params, cfg, ctx, batch["tokens"],
                             batch.get("img_embeds"))
-    labels = batch["labels"]
+    loss, n = cross_entropy(logits, batch["labels"], cfg)
+    return loss, {"loss": loss, "tokens": n}
+
+
+def head_loss(params, cfg: ModelConfig, x, labels) -> jax.Array:
+    """Final norm, vocab projection and mean CE of a stack's output ``x``
+    (the last pipeline stage's share of :func:`loss_fn`)."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return cross_entropy(_project_logits(x, params, cfg), labels, cfg)[0]
+
+
+def cross_entropy(logits, labels, cfg: ModelConfig):
+    """``(mean CE over labels >= 0, count of those labels)``."""
     lf = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(lf, axis=-1)
     safe = jnp.maximum(labels, 0)
@@ -77,8 +90,7 @@ def loss_fn(params, cfg: ModelConfig, ctx: ShardCtx, batch) -> Tuple[jax.Array, 
     nll = lse - picked
     mask = (labels >= 0).astype(jnp.float32)
     n = jnp.maximum(mask.sum(), 1.0)
-    loss = (nll * mask).sum() / n
-    return loss, {"loss": loss, "tokens": n}
+    return (nll * mask).sum() / n, n
 
 
 # ---------------------------------------------------------------------------

@@ -62,7 +62,7 @@ class StragglerWatchdog:
 class TrainLoopConfig:
     total_steps: int
     ckpt_every: int = 50
-    ckpt_dir: str = "checkpoints"
+    ckpt_dir: Optional[str] = "checkpoints"     # None: no checkpoints
     keep: int = 3
     log_every: int = 10
     metrics_path: Optional[str] = None
@@ -78,12 +78,15 @@ class TrainLoop:
         ``plan``: the serialized configurator decision this run executes
         (from ``Planner.plan`` or ``Plan.load``).  Persisted to
         ``<ckpt_dir>/plan.json`` on ``run()`` so restarts and audits see
-        the same artifact the launcher consumed."""
+        the same artifact the launcher consumed.  With
+        ``cfg.ckpt_dir=None`` the loop neither checkpoints, resumes nor
+        persists the plan."""
         self.cfg = cfg
         self.step_fn = step_fn
         self.loader = loader
         self.watchdog = watchdog or StragglerWatchdog()
-        self.ckpt = CheckpointManager(cfg.ckpt_dir, keep=cfg.keep)
+        self.ckpt = (CheckpointManager(cfg.ckpt_dir, keep=cfg.keep)
+                     if cfg.ckpt_dir else None)
         self.fail_at_step = fail_at_step
         self.plan = plan
         self.history: list = []
@@ -92,11 +95,11 @@ class TrainLoop:
         return os.path.join(str(self.cfg.ckpt_dir), "plan.json")
 
     def run(self, params, opt_state, *, resume: bool = True):
-        if self.plan is not None:
+        if self.plan is not None and self.ckpt is not None:
             os.makedirs(str(self.cfg.ckpt_dir), exist_ok=True)
             self.plan.save(self.plan_path())
         start = 0
-        if resume:
+        if resume and self.ckpt is not None:
             latest = self.ckpt.latest_step()
             if latest is not None:
                 (params, opt_state), _ = self.ckpt.restore((params, opt_state),
@@ -121,10 +124,12 @@ class TrainLoop:
                 if metrics_file and step % self.cfg.log_every == 0:
                     metrics_file.write(json.dumps(rec) + "\n")
                     metrics_file.flush()
-                if (step + 1) % self.cfg.ckpt_every == 0 or \
-                        (step + 1) == self.cfg.total_steps:
+                if self.ckpt is not None and (
+                        (step + 1) % self.cfg.ckpt_every == 0
+                        or (step + 1) == self.cfg.total_steps):
                     self.ckpt.save(step + 1, (params, opt_state))
-            self.ckpt.wait()
+            if self.ckpt is not None:
+                self.ckpt.wait()
             return params, opt_state
         finally:
             if metrics_file:

@@ -7,20 +7,7 @@ import sys
 import textwrap
 from pathlib import Path
 
-import jax
-import pytest
-
 SRC = str(Path(__file__).resolve().parent.parent / "src")
-
-# Three tests drive the explicit-mesh API (jax.sharding.AxisType +
-# jax.set_mesh, jax >= 0.6); on older runtimes the multi-device mesh path
-# is unavailable, so they skip cleanly instead of failing in the
-# subprocess (which runs the same jax as this process).
-MODERN_MESH = hasattr(jax.sharding, "AxisType") and hasattr(jax, "set_mesh")
-needs_modern_mesh = pytest.mark.skipif(
-    not MODERN_MESH,
-    reason="multi-device mesh API unavailable: jax.sharding.AxisType / "
-           f"jax.set_mesh missing on jax {jax.__version__}")
 
 
 def run_py(code: str, devices: int = 8, timeout: int = 600):
@@ -34,7 +21,6 @@ def run_py(code: str, devices: int = 8, timeout: int = 600):
     return r.stdout
 
 
-@needs_modern_mesh
 def test_moe_shard_map_matches_single_device():
     out = run_py("""
         import jax, jax.numpy as jnp
@@ -66,7 +52,6 @@ def test_moe_shard_map_matches_single_device():
     assert "OK" in out
 
 
-@needs_modern_mesh
 def test_uneven_head_seq_sharding_matches():
     """granite-style head count (not divisible by model axis): the
     seq-sharded attention path must agree with single-device math."""
@@ -99,7 +84,6 @@ def test_uneven_head_seq_sharding_matches():
     assert "OK" in out
 
 
-@needs_modern_mesh
 def test_pipeline_parallel_loss_and_grads_match():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
