@@ -50,6 +50,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from .cluster import ClusterSpec, compute_slowdowns
 from .dedication import (DedicationEngine, GroupIndex, PairCache, SAResult,
                          perm_to_mapping)
@@ -406,13 +407,6 @@ def dedicate_candidates(survivors: Sequence[Conf],
     if backend not in ("numpy", "jax"):
         raise ValueError(f"unified driver needs backend numpy|jax, "
                          f"got {backend!r}")
-    hier = budget.hierarchical
-    if hier is None:
-        hier = spec.n_gpus >= HIER_AUTO_GPUS
-    islands = build_islands(spec, hierarchical=hier)
-    plan = make_move_plan([len(i) for i in islands], budget.sa_iters,
-                          budget.n_chains, seed)
-    orderings = coarse_orderings(islands, spec)
     warm = getattr(budget, "warm_start", None)
     warm_perm = (None if warm is None
                  else np.asarray(warm, dtype=np.int64))
@@ -427,45 +421,60 @@ def dedicate_candidates(survivors: Sequence[Conf],
                 return warm_perm, offsets, wval
         return init_perm, offsets, cval
 
-    # vpp joins the shape key: vpp variants of one (pp, tp, cp, dp) carry
-    # different stage_work/partition profiles, which the engines share
-    # per group
-    groups: Dict[Tuple[int, int, int, int, int], List[int]] = {}
-    for i in sa_idx:
-        c = survivors[i]
-        groups.setdefault((c.pp, c.tp, c.cp, c.dp, c.vpp), []).append(i)
+    with obs.span("sa.prepare"):
+        hier = budget.hierarchical
+        if hier is None:
+            hier = spec.n_gpus >= HIER_AUTO_GPUS
+        islands = build_islands(spec, hierarchical=hier)
+        plan = make_move_plan([len(i) for i in islands], budget.sa_iters,
+                              budget.n_chains, seed)
+        orderings = coarse_orderings(islands, spec)
 
-    # The O(G^2) pair matrices depend only on (bw, spec): build them once
-    # and share across every engine of every shape group (the jax groups
-    # additionally share the big device buffers via ``device_pairs``).
-    pairs = PairCache.build(bw, spec.gpus_per_node)
+        # vpp joins the shape key: vpp variants of one (pp, tp, cp, dp)
+        # carry different stage_work/partition profiles, which the engines
+        # share per group
+        groups: Dict[Tuple[int, int, int, int, int], List[int]] = {}
+        for i in sa_idx:
+            c = survivors[i]
+            groups.setdefault((c.pp, c.tp, c.cp, c.dp, c.vpp), []).append(i)
+
+        # The O(G^2) pair matrices depend only on (bw, spec): build them
+        # once and share across every engine of every shape group (the jax
+        # groups additionally share the big device buffers via
+        # ``device_pairs``).
+        pairs = PairCache.build(bw, spec.gpus_per_node)
     device_pairs = None
 
+    # per group: engine construction (the first jax group also uploads the
+    # pair matrices), coarse assignment (the first ``score`` traces and
+    # compiles), then the anneal (its compile, dispatch and readback)
     results: Dict[int, SAResult] = {}
-    for shape, idxs in groups.items():
-        t0 = time.perf_counter()
+    for g, idxs in enumerate(groups.values()):
         if backend == "jax":
-            from .jax_engine import JaxDedicationEngine
-            jeng = JaxDedicationEngine([survivors[i] for i in idxs],
-                                       [profiles[i] for i in idxs], bw,
-                                       spec, compute_aware=compute_aware,
-                                       pairs=pairs,
-                                       device_pairs=device_pairs)
-            device_pairs = jeng.device_pairs
-            coarse = {i: pick_init(_JaxCandScorer(jeng, ci),
-                                   coarse_assign(_JaxCandScorer(jeng, ci),
-                                                 islands, orderings))
-                      for ci, i in enumerate(idxs)}
-            init = np.stack([coarse[i][0] for i in idxs])
-            abs_pos = [_abs_positions(plan, coarse[i][1]) for i in idxs]
-            pas = np.stack([a[0] for a in abs_pos])
-            pbs = np.stack([a[1] for a in abs_pos])
-            ppas = np.stack([a[2] for a in abs_pos])
-            ppbs = np.stack([a[3] for a in abs_pos])
-            bests, best_perms, _, accs, accbs = jeng.anneal(
-                init, pas, pbs, plan.kind, plan.thresh, plan.valid,
-                ppas, ppbs, plan.probe_kind, alpha=_ALPHA)
-            elapsed = time.perf_counter() - t0
+            with obs.span("sa.engine", group=g) as s_eng:
+                from .jax_engine import JaxDedicationEngine
+                jeng = JaxDedicationEngine([survivors[i] for i in idxs],
+                                           [profiles[i] for i in idxs], bw,
+                                           spec, compute_aware=compute_aware,
+                                           pairs=pairs,
+                                           device_pairs=device_pairs)
+                device_pairs = jeng.device_pairs
+            with obs.span("sa.coarse", group=g) as s_coarse:
+                coarse = {i: pick_init(_JaxCandScorer(jeng, ci),
+                                       coarse_assign(_JaxCandScorer(jeng, ci),
+                                                     islands, orderings))
+                          for ci, i in enumerate(idxs)}
+            with obs.span("sa.anneal", group=g) as s_anneal:
+                init = np.stack([coarse[i][0] for i in idxs])
+                abs_pos = [_abs_positions(plan, coarse[i][1]) for i in idxs]
+                pas = np.stack([a[0] for a in abs_pos])
+                pbs = np.stack([a[1] for a in abs_pos])
+                ppas = np.stack([a[2] for a in abs_pos])
+                ppbs = np.stack([a[3] for a in abs_pos])
+                bests, best_perms, _, accs, accbs = jeng.anneal(
+                    init, pas, pbs, plan.kind, plan.thresh, plan.valid,
+                    ppas, ppbs, plan.probe_kind, alpha=_ALPHA)
+            elapsed = s_eng.seconds + s_coarse.seconds + s_anneal.seconds
             iters = int(plan.chain_iters.sum())
             for ci, i in enumerate(idxs):
                 lats = [float(v) for v in bests[ci]]
@@ -476,37 +485,40 @@ def dedicate_candidates(survivors: Sequence[Conf],
                                         int(accs[ci].sum()),
                                         int(accbs[ci][win]))
         else:
-            gidx = GroupIndex.build(survivors[idxs[0]])
-            engines = {i: DedicationEngine(survivors[i], bw, profiles[i],
-                                           spec, index=gidx,
-                                           compute_aware=compute_aware,
-                                           pairs=pairs)
-                       for i in idxs}
-            coarse = {i: pick_init(engines[i],
-                                   coarse_assign(engines[i], islands,
-                                                 orderings))
-                      for i in idxs}
-            for i in idxs:
-                tc = time.perf_counter()
-                deadline = tc + budget.sa_seconds
-                init_perm, offsets, cval = coarse[i]
-                lats, perms, iters, accs, accbs = [], [], 0, [], []
-                for k in range(plan.n_chains):
-                    if time.perf_counter() >= deadline and lats:
-                        break                  # out of wall-clock budget
-                    b, p, it, ac, ab = _run_chain_numpy(
-                        engines[i], init_perm, offsets, plan, k, _ALPHA)
-                    lats.append(b)
-                    perms.append(p)
-                    iters += it
-                    accs.append(ac)
-                    accbs.append(ab)
-                win = int(np.argmin(lats))
-                results[i] = _to_result(survivors[i], perms[win],
-                                        float(lats[win]), cval, iters,
-                                        time.perf_counter() - tc,
-                                        [float(v) for v in lats],
-                                        sum(accs), accbs[win])  # repro: noqa DET004 -- accepted-move counters are ints; integer addition is order-independent
+            with obs.span("sa.engine", group=g):
+                gidx = GroupIndex.build(survivors[idxs[0]])
+                engines = {i: DedicationEngine(survivors[i], bw, profiles[i],
+                                               spec, index=gidx,
+                                               compute_aware=compute_aware,
+                                               pairs=pairs)
+                           for i in idxs}
+            with obs.span("sa.coarse", group=g):
+                coarse = {i: pick_init(engines[i],
+                                       coarse_assign(engines[i], islands,
+                                                     orderings))
+                          for i in idxs}
+            with obs.span("sa.anneal", group=g):
+                for i in idxs:
+                    tc = time.perf_counter()
+                    deadline = tc + budget.sa_seconds
+                    init_perm, offsets, cval = coarse[i]
+                    lats, perms, iters, accs, accbs = [], [], 0, [], []
+                    for k in range(plan.n_chains):
+                        if time.perf_counter() >= deadline and lats:
+                            break              # out of wall-clock budget
+                        b, p, it, ac, ab = _run_chain_numpy(
+                            engines[i], init_perm, offsets, plan, k, _ALPHA)
+                        lats.append(b)
+                        perms.append(p)
+                        iters += it
+                        accs.append(ac)
+                        accbs.append(ab)
+                    win = int(np.argmin(lats))
+                    results[i] = _to_result(survivors[i], perms[win],
+                                            float(lats[win]), cval, iters,
+                                            time.perf_counter() - tc,
+                                            [float(v) for v in lats],
+                                            sum(accs), accbs[win])  # repro: noqa DET004 -- accepted-move counters are ints; integer addition is order-independent
     return results
 
 

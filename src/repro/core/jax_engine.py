@@ -42,6 +42,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .. import obs
 from .cluster import ClusterSpec, compute_slowdowns
 from .dedication import PairCache
 from .simulator import Conf, Profile
@@ -358,6 +359,7 @@ class JaxDedicationEngine:
                   for k, v in self._sc.items()}
             p = jnp.asarray(np.asarray(perm), dtype=jnp.int32)
             if self._jit_score is None:
+                obs.count_trace("jax_engine.score")
                 self._jit_score = jax.jit(self._score_one).lower(
                     p, sc, self._env).compile()
             return float(self._jit_score(p, sc, self._env))
@@ -376,6 +378,7 @@ class JaxDedicationEngine:
             p = jnp.asarray(np.asarray(perms), dtype=jnp.int32)
             exe = self._batch_cache.get(p.shape)
             if exe is None:
+                obs.count_trace("jax_engine.score_batch")
                 exe = jax.jit(jax.vmap(self._score_one,
                                        in_axes=(0, None, None))).lower(
                     p, sc, self._env).compile()
@@ -457,6 +460,7 @@ class JaxDedicationEngine:
         key = (tuple(np.shape(a) for a in args[:9]), alpha)
         exe = self._anneal_cache.get(key)
         if exe is None:
+            obs.count_trace("jax_engine.anneal")
             with jax.enable_x64(True):
                 exe = jax.jit(self._build_anneal(alpha)).lower(
                     *args).compile()

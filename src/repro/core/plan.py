@@ -36,6 +36,7 @@ from typing import ClassVar, Optional, Protocol, Tuple, runtime_checkable
 
 import numpy as np
 
+from .. import obs
 from .baselines import amp_configure, mlm_configure, varuna_configure
 from .cluster import ClusterSpec, tier_fingerprint
 from .memory import MemoryEstimator
@@ -727,6 +728,9 @@ class Planner:
              keep_top: int = 10, lineage: Optional[dict] = None) -> Plan:
         """Run the strategy and freeze its result into a :class:`Plan`.
 
+        Each call is one request of :mod:`repro.obs`: the spans inside
+        carry its id.
+
         Args:
             req: declarative request.
             bw: ``(G, G)`` profiled bandwidth matrix.
@@ -736,12 +740,13 @@ class Planner:
                 which cached neighbor seeded a warm start); ``None`` for
                 a direct cold search.
         """
-        res = self.strategy.search(req, bw)
-        # provenance must fingerprint the matrix the strategy actually
-        # scored against (MegatronStrategy may substitute its bw_true)
-        scoring_bw = getattr(self.strategy, "scoring_bw", None)
-        return Plan.from_search(
-            res, req, scoring_bw(bw) if scoring_bw is not None else bw,
-            strategy=self.strategy.name,
-            estimator=getattr(self.strategy, "estimator", None),
-            keep_top=keep_top, lineage=lineage)
+        with obs.request():
+            res = self.strategy.search(req, bw)
+            # provenance must fingerprint the matrix the strategy actually
+            # scored against (MegatronStrategy may substitute its bw_true)
+            scoring_bw = getattr(self.strategy, "scoring_bw", None)
+            return Plan.from_search(
+                res, req, scoring_bw(bw) if scoring_bw is not None else bw,
+                strategy=self.strategy.name,
+                estimator=getattr(self.strategy, "estimator", None),
+                keep_top=keep_top, lineage=lineage)

@@ -49,6 +49,7 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from .cluster import ClusterSpec
 from .dedication import (DedicationEngine, GroupIndex, PairCache, SAResult,
                          anneal, anneal_multistart)
@@ -95,7 +96,9 @@ class Candidate:
 class Overhead:
     """Typed search-overhead breakdown (the paper's Table II axis).
 
-    The ``*_s`` fields are wall-clock phase timings of the staged pipeline;
+    The ``*_s`` fields are wall-clock phase timings of the staged pipeline:
+    stages 1-4 are the seconds of their :mod:`repro.obs` spans, ``sa_s``
+    the whole of stage 5 and ``total_s`` the search end to end;
     ``n_enumerated``/``n_candidates`` are the deterministic size counters.
     ``sa_accepted`` is the total number of accepted SA moves across every
     annealed candidate and chain; ``sa_accepted_to_best`` is the accepted
@@ -183,8 +186,10 @@ class BatchSearchContext:
         n_predict_batches: how many jitted ``predict_batch`` forwards this
             context has issued (0 without an estimator, else exactly 1) —
             observable proof of request batching for tests and benchmarks.
-        build_s / enumerate_s / mem_estimator_s / profile_s / prescore_s:
-            wall-clock timings of the shared stages; every member request's
+        enumerate_s / mem_estimator_s / profile_s / prescore_s: seconds
+            of the shared stages' spans (``search.enumerate``,
+            ``search.mem_estimate``, ``search.profile``,
+            ``search.prescore``); every member request's
             :class:`Overhead` reports these same (un-amortized) values.
     """
 
@@ -194,7 +199,6 @@ class BatchSearchContext:
                  max_micro: int = 16, fixed_micro: Optional[int] = None,
                  estimator: Optional[MemoryEstimator] = None,
                  mem_limit: Optional[float] = None) -> None:
-        t0 = time.perf_counter()
         self.workload = workload
         self.spec = spec
         self.bw = bw
@@ -208,16 +212,16 @@ class BatchSearchContext:
         w = workload
 
         # stage 1: enumerate the whole (union) search space up front
-        confs = [conf for conf in enumerate_confs(spec.n_gpus, w.bs_global,
-                                                  n_layers=w.cfg.n_layers,
-                                                  max_cp=max_cp,
-                                                  max_tp=max_tp,
-                                                  seq=w.seq,
-                                                  max_vpp=max_vpp)
-                 if conf.bs_micro <= max_micro
-                 and (fixed_micro is None or conf.bs_micro == fixed_micro)]
+        with obs.span("search.enumerate") as s_enum:
+            confs = [conf for conf in enumerate_confs(
+                         spec.n_gpus, w.bs_global, n_layers=w.cfg.n_layers,
+                         max_cp=max_cp, max_tp=max_tp, seq=w.seq,
+                         max_vpp=max_vpp)
+                     if conf.bs_micro <= max_micro
+                     and (fixed_micro is None
+                          or conf.bs_micro == fixed_micro)]
         self._confs = confs
-        self.enumerate_s = time.perf_counter() - t0
+        self.enumerate_s = s_enum.seconds
 
         # partition-aware profile cache; also the resolver of each conf's
         # chunk partition (None = uniform -> every legacy bit-exact path)
@@ -225,50 +229,51 @@ class BatchSearchContext:
 
         # stage 2: batched memory pruning — one jitted forward for all
         # confs in the union
-        tm = time.perf_counter()
-        if estimator is not None and confs:
-            preds = estimator.predict_batch(w.cfg, confs)
-            self.n_predict_batches = 1
-            # The estimator was fit on the uniform-split ground truth; a
-            # non-uniform partition / interleaved schedule shifts the
-            # worst-stage peak, so rescale its prediction by the
-            # ground-truth ratio.  Uniform plain-1F1B configs skip this
-            # entirely (ratio would be exactly 1), keeping legacy
-            # predictions bit-identical.
-            for i, c in enumerate(confs):
-                part = self._prof_cache.partition_for(c)
-                if part is None and c.vpp == 1:
-                    continue
-                legacy = ground_truth_memory(
-                    w, dataclasses.replace(c, vpp=1), spec)
-                actual = ground_truth_memory(w, c, spec, partition=part)
-                preds[i] *= actual / legacy
-            self._keep = np.asarray(
-                preds <= self.mem_limit * estimator.soft_margin, dtype=bool)
-            self._mem_preds = preds
-        else:
-            self._keep = np.ones(len(confs), dtype=bool)
-            self._mem_preds = np.full(len(confs), float("nan"))
-        self.mem_estimator_s = time.perf_counter() - tm
+        with obs.span("search.mem_estimate") as s_mem:
+            if estimator is not None and confs:
+                preds = estimator.predict_batch(w.cfg, confs)
+                self.n_predict_batches = 1
+                # The estimator was fit on the uniform-split ground truth;
+                # a non-uniform partition / interleaved schedule shifts the
+                # worst-stage peak, so rescale its prediction by the
+                # ground-truth ratio.  Uniform plain-1F1B configs skip this
+                # entirely (ratio would be exactly 1), keeping legacy
+                # predictions bit-identical.
+                for i, c in enumerate(confs):
+                    part = self._prof_cache.partition_for(c)
+                    if part is None and c.vpp == 1:
+                        continue
+                    legacy = ground_truth_memory(
+                        w, dataclasses.replace(c, vpp=1), spec)
+                    actual = ground_truth_memory(w, c, spec, partition=part)
+                    preds[i] *= actual / legacy
+                self._keep = np.asarray(
+                    preds <= self.mem_limit * estimator.soft_margin,
+                    dtype=bool)
+                self._mem_preds = preds
+            else:
+                self._keep = np.ones(len(confs), dtype=bool)
+                self._mem_preds = np.full(len(confs), float("nan"))
+        self.mem_estimator_s = s_mem.seconds
 
         # stage 3: profiles only for union survivors, memoized per
         # (pp, tp, cp, bs_micro, vpp, partition)
-        tp0 = time.perf_counter()
-        surv = [i for i in range(len(confs)) if self._keep[i]]
-        self._profiles = {i: self._prof_cache.get(confs[i]) for i in surv}
-        self.profile_s = time.perf_counter() - tp0
+        with obs.span("search.profile") as s_prof:
+            surv = [i for i in range(len(confs)) if self._keep[i]]
+            self._profiles = {i: self._prof_cache.get(confs[i])
+                              for i in surv}
+        self.profile_s = s_prof.seconds
 
         # stage 4: one cached pass over every union survivor's default
         # mapping; per-conf values are independent, so indexing this by a
         # request's conf subset reproduces its standalone pre-score
-        ts0 = time.perf_counter()
-        self._base_lat = np.full(len(confs), float("nan"))
-        if surv:
-            self._base_lat[surv] = default_mapping_latencies(
-                [confs[i] for i in surv], [self._profiles[i] for i in surv],
-                bw, spec)
-        self.prescore_s = time.perf_counter() - ts0
-        self.build_s = time.perf_counter() - t0
+        with obs.span("search.prescore") as s_pre:
+            self._base_lat = np.full(len(confs), float("nan"))
+            if surv:
+                self._base_lat[surv] = default_mapping_latencies(
+                    [confs[i] for i in surv],
+                    [self._profiles[i] for i in surv], bw, spec)
+        self.prescore_s = s_pre.seconds
 
     @classmethod
     def for_requests(cls, reqs: Sequence["PlanRequest"], bw: np.ndarray, *,
@@ -374,10 +379,13 @@ class BatchSearchContext:
         mem_preds = self._mem_preds[surv_idx]
 
         # stage 5: SA dedication — exhaustive, or concentrated on the
-        # top-k by pre-score
+        # top-k by pre-score.  Its time is no span: a profiler trace names
+        # each idle gap after the outermost span over it, so one around
+        # the stage would hide the SA driver's own spans.
         sa_time = 0.0
         cands: List[Candidate] = []
         if dedicate and survivors:
+            ts = time.perf_counter()
             if sa_topk is None or sa_topk >= len(survivors):
                 sa_set = set(range(len(survivors)))
             else:
@@ -389,11 +397,9 @@ class BatchSearchContext:
                 # annealer (byte-identical results); candidates batched
                 # per shape; warm_start is read off the budget inside
                 from .annealing import dedicate_candidates
-                ts = time.perf_counter()
                 sa_res = dedicate_candidates(survivors, profiles,
                                              sorted(sa_set), bw, spec,
                                              budget, seed)
-                sa_time = time.perf_counter() - ts
                 for i, conf in enumerate(survivors):
                     if i in sa_res:
                         cands.append(Candidate(conf, sa_res[i].mapping,
@@ -423,7 +429,6 @@ class BatchSearchContext:
                     pair_cache = PairCache.build(bw, spec.gpus_per_node)
                 engine = DedicationEngine(conf, bw, prof, spec, index=gidx,
                                           pairs=pair_cache)
-                ts = time.perf_counter()
                 if n_chains > 1:
                     res = anneal_multistart(conf, bw, prof, spec,
                                             n_chains=n_chains,
@@ -436,9 +441,9 @@ class BatchSearchContext:
                                  time_limit_s=sa_seconds,
                                  max_iters=sa_iters, seed=seed,
                                  init_perm=warm_perm, engine=engine)
-                sa_time += time.perf_counter() - ts
                 cands.append(Candidate(conf, res.mapping, res.latency,
                                        float(mem_preds[i]), sa=res))
+            sa_time = time.perf_counter() - ts
         else:
             for i, conf in enumerate(survivors):
                 cands.append(Candidate(conf, default_mapping(conf),
@@ -460,7 +465,9 @@ class BatchSearchContext:
             best=best,
             ranked=cands,
             overhead=Overhead(
-                total_s=self.build_s + (time.perf_counter() - t0),
+                total_s=(self.enumerate_s + self.mem_estimator_s
+                         + self.profile_s + self.prescore_s
+                         + time.perf_counter() - t0),
                 sa_s=sa_time, mem_estimator_s=self.mem_estimator_s,
                 enumerate_s=self.enumerate_s, profile_s=self.profile_s,
                 prescore_s=self.prescore_s,
