@@ -447,7 +447,8 @@ def dedicate_candidates(survivors: Sequence[Conf],
 
     # per group: engine construction (the first jax group also uploads the
     # pair matrices), coarse assignment (the first ``score`` traces and
-    # compiles), then the anneal (its compile, dispatch and readback)
+    # compiles unless the process already holds its executable), then the
+    # anneal (likewise its compile, then dispatch and readback)
     results: Dict[int, SAResult] = {}
     for g, idxs in enumerate(groups.values()):
         if backend == "jax":

@@ -35,9 +35,21 @@ What "equal" means therefore depends on the platform:
   the scorer's float64 is emulated), so a score is within
   :data:`TPU_REL_TOL` of the float64 reference ``pipette_latency_ref``,
   not bit-equal to it.
+
+The scorer and the annealer are module-level functions of a hashable
+:class:`Statics` record and their array arguments, and their compiled
+executables are kept in one process-level LRU cache
+(:data:`EXE_CACHE_SIZE` entries) keyed by the function, the statics and
+each argument's shape, dtype and sharding.  Engines of one static
+structure share one trace and one compile, so a planner that plans
+again for shapes it has seen traces nothing; a hit fires
+``/pipette/exe_hit/<name>`` where a miss fires ``/pipette/trace/<name>``.
 """
 from __future__ import annotations
 
+import collections
+import dataclasses
+import threading
 from typing import Optional, Sequence
 
 import numpy as np
@@ -136,6 +148,249 @@ def _apply_move(perm, pos, kind, pa, pb):
     return perm[src]
 
 
+@dataclasses.dataclass(frozen=True)
+class Statics:
+    """What the traced scorer reads besides its arguments: the shape, the
+    group-reduce implementation (:func:`kernels_mode`), which latency
+    combination applies and the reference bandwidths.  Hashable and free
+    of arrays, it keys the executable cache together with the arguments'
+    avals."""
+    pp: int
+    tp: int
+    cp: int
+    dp: int
+    vpp: int
+    kmode: str
+    #: per-device compute slowdowns apply (``env["slow"]`` is set)
+    tiered: bool
+    #: non-uniform partition or interleaved schedule: the per-stage
+    #: combination even without device tiers
+    nonuniform: bool
+    #: bandwidths the TP and CP times were profiled at.  Constants of the
+    #: trace, not arguments: as an argument the scale kernel reads its
+    #: SMEM scalar from HBM on every call, which took each call 2-12x as
+    #: long on a TPU v5e
+    tp_ref: float
+    cp_ref: float
+
+    @property
+    def n(self) -> int:
+        return self.pp * self.tp * self.cp * self.dp
+
+    @property
+    def nc(self) -> int:
+        return self.tp * self.cp * self.dp
+
+    @property
+    def tpc(self) -> int:
+        return self.tp * self.cp
+
+
+def _group_scales(st: Statics, sub, ref_bw):
+    if st.kmode == "ref":
+        return group_min_scale_ref(sub, ref_bw)
+    return group_min_scale(sub, ref_bw, interpret=(st.kmode == "interpret"))
+
+
+def _group_max(st: Statics, vals):
+    if st.kmode == "ref":
+        return group_max_ref(vals)
+    return group_max(vals, interpret=(st.kmode == "interpret"))
+
+
+def _score_one(st: Statics, perm, sc, env):
+    """Full Eq. 3-6 evaluation of one permutation for one candidate's
+    scalars ``sc``; every reduction mirrors ``DedicationEngine`` (see the
+    module docstring for what that makes equal on each platform)."""
+    pp, tp, cp, dp = st.pp, st.tp, st.cp, st.dp
+    nc, tpc = st.nc, st.tpc
+
+    if tp > 1:
+        g = perm.reshape(-1, tp)
+        sub = env["bw_noself"][g[:, :, None], g[:, None, :]]
+        tp_scale = jnp.maximum(1.0, _group_scales(st, sub, st.tp_ref).max())
+    else:
+        tp_scale = 1.0
+
+    if cp > 1:
+        g = perm.reshape(pp * dp, cp, tp).transpose(0, 2, 1).reshape(-1, cp)
+        sub = env["bw_noself"][g[:, :, None], g[:, None, :]]
+        cp_scale = jnp.maximum(1.0, _group_scales(st, sub, st.cp_ref).max())
+    else:
+        cp_scale = 1.0
+
+    if pp > 1:
+        src = perm[:(pp - 1) * nc].reshape(pp - 1, nc)
+        dst = perm[nc:].reshape(pp - 1, nc)
+        hop = sc["hopf"] / env["bw"][src, dst]
+        t = hop[0]
+        for x in range(1, pp - 1):       # reference left-to-right fold
+            t = t + hop[x]
+        t_pp = jnp.maximum(0.0, t.max())
+    else:
+        t_pp = 0.0
+
+    # stage-0 DP hierarchical all-reduce (Eq. 6); the only DP groups on
+    # the critical path — mirrors DedicationEngine._dp0_times
+    ids = perm[:nc].reshape(dp, tpc).T                    # (tpc, dp)
+    ii, jj = ids[:, :, None], ids[:, None, :]
+    sym = env["sym_intra"][ii, jj]
+    member_min = sym.min(axis=2)
+    same = jnp.isfinite(sym)
+    counts = same.sum(axis=2) + 1  # repro: noqa DET003 -- boolean mask count: integer reduction, exact in any association order
+    intra = (env["intra_coef"][counts] / member_min).max(axis=1)
+    is_rep = ~(same & env["jlt"]).any(axis=2)
+    n_reps = is_rep.sum(axis=1)  # repro: noqa DET003 -- boolean mask count: integer reduction, exact in any association order
+    pair = is_rep[:, :, None] & is_rep[:, None, :]
+    rep_min = jnp.where(pair, env["bw_noself"][ii, jj],
+                        jnp.inf).min(axis=(1, 2))
+    inter = env["inter_coef"][n_reps] / rep_min
+    t_dp = jnp.maximum(0.0, (intra + inter).max())
+
+    t_tp = sc["tsum_tp"] * tp_scale
+    t_cm = t_tp + sc["tsum_cp"] * cp_scale
+    if st.tiered or st.nonuniform:
+        if st.tiered:
+            sv = _group_max(st, env["slow"][perm.reshape(pp, nc)])
+            c_x = sc["cw"] * sv
+        else:
+            # homogeneous fleet, non-uniform stage_work: the NumPy
+            # engine's stage scales are all 1.0, and cw * 1.0 == cw
+            # exactly, so using cw directly preserves bit parity
+            c_x = sc["cw"]
+        c_max = c_x.max()
+        c_sum = np_pairwise_sum(c_x, pp)
+        if st.vpp == 1:
+            t_bubble = float(pp) * (c_max + t_cm) + t_pp
+            return ((t_bubble * sc["r"] + (c_sum - c_max))
+                    + float(pp - 1) * t_cm) + t_dp
+        # interleaved-1F1B: mirrors _hetero_combine's vpp branch in
+        # NumPy's left-to-right association order
+        t_bubble = float(pp) * (c_max + t_cm) + float(st.vpp) * t_pp
+        return ((t_bubble * sc["r"] + (c_sum - c_max) / float(st.vpp))
+                + float(pp - 1) * t_cm / float(st.vpp)) + t_dp
+    t_bubble = float(pp) * (sc["c"] + t_cm) + t_pp
+    t_straggler = float(pp - 1) * (sc["c"] + t_cm)
+    return (t_bubble * sc["r"] + t_straggler) + t_dp
+
+
+def _score_many(st: Statics, perms, sc, env):
+    """:func:`_score_one` over a leading batch axis of ``perms``."""
+    return jax.vmap(lambda perm: _score_one(st, perm, sc, env))(perms)
+
+
+def _anneal(st: Statics, alpha: float, init_perm, pas, pbs, kinds, thresh,
+            valid, ppas, ppbs, pkinds, sc, env):
+    """Every chain of every candidate (see :meth:`JaxDedicationEngine.
+    anneal` for the arguments): ``run_chain`` vmapped over chains, then
+    over candidates."""
+    pos = jnp.arange(st.n, dtype=jnp.int32)
+
+    def run_chain(init_perm, pas, pbs, kinds, thresh, valid,
+                  ppas, ppbs, pkinds, sc, env):
+        cur0 = _score_one(st, init_perm, sc, env)
+
+        def probe(carry, xs):
+            pk, pa, pb = xs
+            val = _score_one(st, _apply_move(init_perm, pos, pk, pa, pb),
+                             sc, env)
+            return jnp.maximum(carry, jnp.abs(val - cur0)), None
+
+        mx, _ = jax.lax.scan(probe, 0.0, (pkinds, ppas, ppbs))
+        temp0 = jnp.maximum(jnp.maximum(mx, cur0 * 1e-3), 1e-12)
+
+        def step(carry, xs):
+            perm, cur, temp, best, bperm, acc, accb = carry
+            kind, pa, pb, thr, ok = xs
+            cand = _apply_move(perm, pos, kind, pa, pb)
+            val = _score_one(st, cand, sc, env)
+            delta = val - cur
+            accept = ok & ((delta <= 0) | (delta < temp * thr))
+            perm = jnp.where(accept, cand, perm)
+            cur = jnp.where(accept, val, cur)
+            acc = acc + accept.astype(acc.dtype)
+            imp = accept & (val < best)
+            best = jnp.where(imp, val, best)
+            bperm = jnp.where(imp, cand, bperm)
+            accb = jnp.where(imp, acc, accb)
+            temp = jnp.where(ok, temp * alpha, temp)
+            return (perm, cur, temp, best, bperm, acc, accb), None
+
+        zero = jnp.zeros((), jnp.int32)
+        carry0 = (init_perm, cur0, temp0, cur0, init_perm, zero, zero)
+        (_, cur, _, best, bperm, acc, accb), _ = jax.lax.scan(
+            step, carry0, (kinds, pas, pbs, thresh, valid))
+        return best, bperm, cur, acc, accb
+
+    over_chains = jax.vmap(
+        run_chain, in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, None, None))
+    over_cands = jax.vmap(
+        over_chains, in_axes=(0, 0, 0, None, None, None, 0, 0, None, 0, None))
+    return over_cands(init_perm, pas, pbs, kinds, thresh, valid, ppas, ppbs,
+                      pkinds, sc, env)
+
+
+# ---------------------------------------------------------------------------
+# the process-level executable cache
+# ---------------------------------------------------------------------------
+
+#: Compiled executables the process keeps, least recently used dropped
+#: first.  A plan uses two per shape group (``score`` and ``anneal``).
+EXE_CACHE_SIZE = 32
+
+_EXE_CACHE: "collections.OrderedDict[tuple, jax.stages.Compiled]" = \
+    collections.OrderedDict()
+_EXE_LOCK = threading.Lock()
+
+
+def clear_executables() -> None:
+    """Drop every kept executable: the next lowering of each traces."""
+    with _EXE_LOCK:
+        _EXE_CACHE.clear()
+
+
+def _aval_key(a) -> tuple:
+    return (tuple(a.shape), a.dtype, getattr(a, "weak_type", False),
+            getattr(a, "sharding", None))
+
+
+def _exe_key(name: str, statics: tuple, args: tuple) -> tuple:
+    """Cache key of ``name`` lowered with ``statics`` for ``args``: the
+    arguments' tree structure and each leaf's shape, dtype, weak type and
+    sharding, so an executable compiled for a described topology is never
+    handed a CPU or chip call."""
+    leaves, tree = jax.tree.flatten(args)
+    return (name, statics, tree, tuple(_aval_key(a) for a in leaves))
+
+
+def _executable(key: tuple, fn, args: tuple):
+    """The process's executable for ``key`` (from :func:`_exe_key`), or
+    ``fn`` compiled for ``args`` with the key's statics as its leading
+    static arguments.
+
+    ``fn`` is a module-level function and the statics hold no array, so
+    an entry keeps no engine and none of its device buffers alive.  A hit
+    fires ``/pipette/exe_hit/<name>``, a miss ``/pipette/trace/<name>``
+    before it traces.  Two threads that miss on one key both compile
+    it."""
+    name, statics = key[:2]
+    with _EXE_LOCK:
+        exe = _EXE_CACHE.get(key)
+        if exe is not None:
+            _EXE_CACHE.move_to_end(key)
+    if exe is not None:
+        obs.count_exe_hit(name)
+        return exe
+    obs.count_trace(name)
+    exe = jax.jit(fn, static_argnums=tuple(range(len(statics)))).lower(
+        *statics, *args).compile()
+    with _EXE_LOCK:
+        _EXE_CACHE[key] = exe
+        while len(_EXE_CACHE) > EXE_CACHE_SIZE:
+            _EXE_CACHE.popitem(last=False)
+    return exe
+
+
 class JaxDedicationEngine:
     """Batched JAX scorer + vmapped multi-chain SA for one (pp, tp, cp, dp)
     shape.
@@ -188,37 +443,35 @@ class JaxDedicationEngine:
                  p0.partition, p0.chunk_work), \
                 "profiles vary within shape; shared tensors invalid"
         self.confs = list(confs)
-        self.pp, self.tp, self.cp, self.dp, self.vpp = shape
-        self.n = conf.n_gpus
-        self.nc = self.tp * self.cp * self.dp
-        self.tpc = self.tp * self.cp
-        self._kmode = kernels_mode(kernels)
-        self._tp_ref = float(p0.tp_ref_bw)
-        self._cp_ref = float(p0.cp_ref_bw)
+        pp, tp, cp, dp, vpp = shape
 
         # host-side constants: the (G, G) pair matrices come from the same
         # PairCache construction the NumPy engine shares (bit-identical by
         # design), the small per-shape tensors are built here
         if pairs is None:
             pairs = PairCache.build(bw, spec.gpus_per_node)
-        jlt = (np.arange(self.dp)[None, :] < np.arange(self.dp)[:, None])
+        jlt = (np.arange(dp)[None, :] < np.arange(dp)[:, None])
         intra_coef = np.array(
             [4 * (c - 1) / c * p0.msg_dp if c else 0.0
-             for c in range(self.dp + 1)])
+             for c in range(dp + 1)])
         inter_coef = np.array(
             [2 * (c - 1) / c * p0.msg_dp if c else 0.0
-             for c in range(self.dp + 1)])
+             for c in range(dp + 1)])
         slow = compute_slowdowns(spec) if compute_aware else None
-        self.tiered = slow is not None
         # Non-uniform partitions / interleaved schedules need the per-stage
         # combination even without device tiers (latency._combine_eq34's
         # trigger, mirrored here so both backends stay bit-identical).
-        self.nonuniform = p0.partition is not None or conf.vpp > 1
+        self.statics = Statics(pp, tp, cp, dp, vpp, kernels_mode(kernels),
+                               tiered=slow is not None,
+                               nonuniform=(p0.partition is not None
+                                           or vpp > 1),
+                               tp_ref=float(p0.tp_ref_bw),
+                               cp_ref=float(p0.cp_ref_bw))
 
         # per-candidate profile scalars (the vmapped axis); all arithmetic
         # on host NumPy f64 so the values equal the NumPy engine's
         w = (np.asarray(p0.stage_work) if p0.stage_work is not None
-             else np.ones(self.pp))
+             else np.ones(pp))
         c_arr = np.array([p.c_fwd + p.c_bwd for p in profs])
         sc = {
             "c": c_arr,
@@ -227,7 +480,8 @@ class JaxDedicationEngine:
             "hopf": np.array([2.0 * p.msg_pp for p in profs]),
             "r": np.array([c.n_mb / c.pp for c in confs]),
             "cw": (c_arr[:, None] * w[None, :]
-                   if self.tiered or self.nonuniform else None),
+                   if self.statics.tiered or self.statics.nonuniform
+                   else None),
         }
 
         # device residency in f64 — arrays must be created inside the
@@ -253,116 +507,34 @@ class JaxDedicationEngine:
             }
             self._sc = {k: (None if v is None else jnp.asarray(v))
                         for k, v in sc.items()}
-        self._jit_score = None
-        self._batch_cache = {}
-        self._anneal_cache = {}
+        self._exes = {}
 
-    # -- the pure scoring function (one perm, one candidate's scalars) ----
-
-    def _group_scales(self, sub, ref_bw):
-        if self._kmode == "ref":
-            return group_min_scale_ref(sub, ref_bw)
-        return group_min_scale(sub, ref_bw,
-                               interpret=(self._kmode == "interpret"))
-
-    def _group_max(self, vals):
-        if self._kmode == "ref":
-            return group_max_ref(vals)
-        return group_max(vals, interpret=(self._kmode == "interpret"))
-
-    def _score_one(self, perm, sc, env):
-        """Full Eq. 3-6 evaluation of one permutation; every reduction
-        mirrors ``DedicationEngine`` (see the module docstring for what
-        that makes equal on each platform)."""
-        pp, tp, cp, dp = self.pp, self.tp, self.cp, self.dp
-        nc, tpc = self.nc, self.tpc
-
-        if tp > 1:
-            g = perm.reshape(-1, tp)
-            sub = env["bw_noself"][g[:, :, None], g[:, None, :]]
-            tp_scale = jnp.maximum(1.0, self._group_scales(
-                sub, self._tp_ref).max())
-        else:
-            tp_scale = 1.0
-
-        if cp > 1:
-            g = perm.reshape(pp * dp, cp, tp).transpose(0, 2, 1) \
-                .reshape(-1, cp)
-            sub = env["bw_noself"][g[:, :, None], g[:, None, :]]
-            cp_scale = jnp.maximum(1.0, self._group_scales(
-                sub, self._cp_ref).max())
-        else:
-            cp_scale = 1.0
-
-        if pp > 1:
-            src = perm[:(pp - 1) * nc].reshape(pp - 1, nc)
-            dst = perm[nc:].reshape(pp - 1, nc)
-            hop = sc["hopf"] / env["bw"][src, dst]
-            t = hop[0]
-            for x in range(1, pp - 1):       # reference left-to-right fold
-                t = t + hop[x]
-            t_pp = jnp.maximum(0.0, t.max())
-        else:
-            t_pp = 0.0
-
-        # stage-0 DP hierarchical all-reduce (Eq. 6); the only DP groups on
-        # the critical path — mirrors DedicationEngine._dp0_times
-        ids = perm[:nc].reshape(dp, tpc).T                    # (tpc, dp)
-        ii, jj = ids[:, :, None], ids[:, None, :]
-        sym = env["sym_intra"][ii, jj]
-        member_min = sym.min(axis=2)
-        same = jnp.isfinite(sym)
-        counts = same.sum(axis=2) + 1  # repro: noqa DET003 -- boolean mask count: integer reduction, exact in any association order
-        intra = (env["intra_coef"][counts] / member_min).max(axis=1)
-        is_rep = ~(same & env["jlt"]).any(axis=2)
-        n_reps = is_rep.sum(axis=1)  # repro: noqa DET003 -- boolean mask count: integer reduction, exact in any association order
-        pair = is_rep[:, :, None] & is_rep[:, None, :]
-        rep_min = jnp.where(pair, env["bw_noself"][ii, jj],
-                            jnp.inf).min(axis=(1, 2))
-        inter = env["inter_coef"][n_reps] / rep_min
-        t_dp = jnp.maximum(0.0, (intra + inter).max())
-
-        t_tp = sc["tsum_tp"] * tp_scale
-        t_cm = t_tp + sc["tsum_cp"] * cp_scale
-        if self.tiered or self.nonuniform:
-            if self.tiered:
-                sv = self._group_max(env["slow"][perm.reshape(pp, nc)])
-                c_x = sc["cw"] * sv
-            else:
-                # homogeneous fleet, non-uniform stage_work: the NumPy
-                # engine's stage scales are all 1.0, and cw * 1.0 == cw
-                # exactly, so using cw directly preserves bit parity
-                c_x = sc["cw"]
-            c_max = c_x.max()
-            c_sum = np_pairwise_sum(c_x, pp)
-            if self.vpp == 1:
-                t_bubble = float(pp) * (c_max + t_cm) + t_pp
-                return ((t_bubble * sc["r"] + (c_sum - c_max))
-                        + float(pp - 1) * t_cm) + t_dp
-            # interleaved-1F1B: mirrors _hetero_combine's vpp branch in
-            # NumPy's left-to-right association order
-            t_bubble = float(pp) * (c_max + t_cm) + float(self.vpp) * t_pp
-            return ((t_bubble * sc["r"] + (c_sum - c_max) / float(self.vpp))
-                    + float(pp - 1) * t_cm / float(self.vpp)) + t_dp
-        t_bubble = float(pp) * (sc["c"] + t_cm) + t_pp
-        t_straggler = float(pp - 1) * (sc["c"] + t_cm)
-        return (t_bubble * sc["r"] + t_straggler) + t_dp
+    def _compiled(self, name: str, fn, statics: tuple, args: tuple):
+        """The executable of ``fn`` for ``args``: the engine's own once it
+        has one, else the process's (:func:`_executable`), so the
+        process-level cache is asked, and counts, once an engine."""
+        key = _exe_key(name, statics, args)
+        exe = self._exes.get(key)
+        if exe is None:
+            exe = self._exes[key] = _executable(key, fn, args)
+        return exe
 
     # -- public scoring (tests / coarse assignment) -----------------------
+
+    def _cand_sc(self, cand: int) -> dict:
+        return {k: (None if v is None else v[cand])
+                for k, v in self._sc.items()}
 
     def score(self, perm: np.ndarray, cand: int = 0) -> float:
         """Full JAX evaluation of ``perm`` for candidate ``cand`` — the
         same value as ``DedicationEngine(confs[cand], ...).score(perm)``
         (bitwise on CPU, within :data:`TPU_REL_TOL` on TPU)."""
         with jax.enable_x64(True):
-            sc = {k: (None if v is None else v[cand])
-                  for k, v in self._sc.items()}
-            p = jnp.asarray(np.asarray(perm), dtype=jnp.int32)
-            if self._jit_score is None:
-                obs.count_trace("jax_engine.score")
-                self._jit_score = jax.jit(self._score_one).lower(
-                    p, sc, self._env).compile()
-            return float(self._jit_score(p, sc, self._env))
+            args = (jnp.asarray(np.asarray(perm), dtype=jnp.int32),
+                    self._cand_sc(cand), self._env)
+            exe = self._compiled("jax_engine.score", _score_one,
+                                 (self.statics,), args)
+            return float(exe(*args))
 
     def score_batch(self, perms: np.ndarray, cand: int = 0) -> np.ndarray:
         """Score a ``(R, n)`` batch of permutations in one vmapped dispatch.
@@ -373,66 +545,13 @@ class JaxDedicationEngine:
         measures against a loop of NumPy-engine full re-scores.
         """
         with jax.enable_x64(True):
-            sc = {k: (None if v is None else v[cand])
-                  for k, v in self._sc.items()}
-            p = jnp.asarray(np.asarray(perms), dtype=jnp.int32)
-            exe = self._batch_cache.get(p.shape)
-            if exe is None:
-                obs.count_trace("jax_engine.score_batch")
-                exe = jax.jit(jax.vmap(self._score_one,
-                                       in_axes=(0, None, None))).lower(
-                    p, sc, self._env).compile()
-                self._batch_cache[p.shape] = exe
-            return np.asarray(exe(p, sc, self._env))
+            args = (jnp.asarray(np.asarray(perms), dtype=jnp.int32),
+                    self._cand_sc(cand), self._env)
+            exe = self._compiled("jax_engine.score_batch", _score_many,
+                                 (self.statics,), args)
+            return np.asarray(exe(*args))
 
     # -- the vmapped multi-chain annealer ---------------------------------
-
-    def _build_anneal(self, alpha: float):
-        pos = jnp.arange(self.n, dtype=jnp.int32)
-
-        def run_chain(init_perm, pas, pbs, kinds, thresh, valid,
-                      ppas, ppbs, pkinds, sc, env):
-            cur0 = self._score_one(init_perm, sc, env)
-
-            def probe(carry, xs):
-                pk, pa, pb = xs
-                val = self._score_one(
-                    _apply_move(init_perm, pos, pk, pa, pb), sc, env)
-                return jnp.maximum(carry, jnp.abs(val - cur0)), None
-
-            mx, _ = jax.lax.scan(probe, 0.0, (pkinds, ppas, ppbs))
-            temp0 = jnp.maximum(jnp.maximum(mx, cur0 * 1e-3), 1e-12)
-
-            def step(carry, xs):
-                perm, cur, temp, best, bperm, acc, accb = carry
-                kind, pa, pb, thr, ok = xs
-                cand = _apply_move(perm, pos, kind, pa, pb)
-                val = self._score_one(cand, sc, env)
-                delta = val - cur
-                accept = ok & ((delta <= 0) | (delta < temp * thr))
-                perm = jnp.where(accept, cand, perm)
-                cur = jnp.where(accept, val, cur)
-                acc = acc + accept.astype(acc.dtype)
-                imp = accept & (val < best)
-                best = jnp.where(imp, val, best)
-                bperm = jnp.where(imp, cand, bperm)
-                accb = jnp.where(imp, acc, accb)
-                temp = jnp.where(ok, temp * alpha, temp)
-                return (perm, cur, temp, best, bperm, acc, accb), None
-
-            zero = jnp.zeros((), jnp.int32)
-            carry0 = (init_perm, cur0, temp0, cur0, init_perm, zero, zero)
-            (_, cur, _, best, bperm, acc, accb), _ = jax.lax.scan(
-                step, carry0, (kinds, pas, pbs, thresh, valid))
-            return best, bperm, cur, acc, accb
-
-        over_chains = jax.vmap(
-            run_chain,
-            in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, None, None))
-        over_cands = jax.vmap(
-            over_chains,
-            in_axes=(0, 0, 0, None, None, None, 0, 0, None, 0, None))
-        return over_cands
 
     def anneal_args(self, init_perms: np.ndarray, pas: np.ndarray,
                     pbs: np.ndarray, kinds: np.ndarray, thresh: np.ndarray,
@@ -456,16 +575,10 @@ class JaxDedicationEngine:
         :meth:`anneal_args`, or ``jax.ShapeDtypeStruct`` s of them).
 
         Executables are shape-specialized and ``alpha`` is baked into the
-        scan body, so both key the per-engine cache."""
-        key = (tuple(np.shape(a) for a in args[:9]), alpha)
-        exe = self._anneal_cache.get(key)
-        if exe is None:
-            obs.count_trace("jax_engine.anneal")
-            with jax.enable_x64(True):
-                exe = jax.jit(self._build_anneal(alpha)).lower(
-                    *args).compile()
-            self._anneal_cache[key] = exe
-        return exe
+        scan body, so both key the process-level executable cache."""
+        with jax.enable_x64(True):
+            return self._compiled("jax_engine.anneal", _anneal,
+                                  (self.statics, alpha), args)
 
     def anneal(self, init_perms: np.ndarray, pas: np.ndarray,
                pbs: np.ndarray, kinds: np.ndarray, thresh: np.ndarray,

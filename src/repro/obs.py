@@ -7,7 +7,9 @@ the two sinks JAX already has:
   ``record_event_duration_secs("/pipette/span/<name>", seconds,
   request=<id>)``; a trace counter fires
   ``record_event("/pipette/trace/<name>", request=<id>)`` each time the
-  program traces a jitted function.  Register a listener
+  program traces a jitted function, and an executable-cache hit fires
+  ``record_event("/pipette/exe_hit/<name>", request=<id>)`` where it
+  would have traced.  Register a listener
   (``jax.monitoring.register_event_duration_secs_listener``,
   ``register_event_listener``) to export them; listeners run on the
   caller's thread.
@@ -32,6 +34,7 @@ import jax
 
 SPAN_EVENT = "/pipette/span/"
 TRACE_EVENT = "/pipette/trace/"
+EXE_HIT_EVENT = "/pipette/exe_hit/"
 
 _request = contextvars.ContextVar("pipette_request", default=0)
 _next_request = itertools.count(1)
@@ -76,3 +79,9 @@ def count_trace(name: str) -> None:
     functions to fire from inside their traces made each plan of a
     2,048-GPU fleet trace for about 0.9 s longer on a TPU v5e.)"""
     jax.monitoring.record_event(TRACE_EVENT + name, request=_request.get())
+
+
+def count_exe_hit(name: str) -> None:
+    """Fire ``/pipette/exe_hit/<name>``: call it where a compiled
+    executable is reused in place of tracing and lowering ``name`` again."""
+    jax.monitoring.record_event(EXE_HIT_EVENT + name, request=_request.get())

@@ -8,6 +8,8 @@ that legitimately records which executor ran.  Re-running either backend
 must also reproduce its own bytes exactly.  Byte identity is the CPU
 contract; the float32 Pallas group-reduce kernels that the TPU runs
 (interpret mode here) must give the same plan within the TPU tolerance."""
+import collections
+import dataclasses
 import json
 
 import pytest
@@ -77,6 +79,34 @@ def test_multi_chain_plans_agree_chain_for_chain():
     a = _plan_json(MIXED, "numpy", n_chains=3)
     b = _plan_json(MIXED, "jax", n_chains=3)
     assert _strip_backend(a) == _strip_backend(b)
+
+
+def test_warm_planner_traces_nothing_and_keeps_byte_parity():
+    """A second plan of a request that differs only in its seed reuses
+    the first plan's executables (two shape groups, each ``score`` and
+    ``anneal``), and is still byte-identical to the NumPy backend's."""
+    import jax
+    from repro import obs
+    bw, _ = profile_bandwidth(MIXED)
+    planner = Planner(PipetteStrategy())
+    planner.plan(_req(MIXED, "jax"), bw)
+    events = collections.Counter()
+
+    def on_event(e, **kw):
+        if e.startswith((obs.TRACE_EVENT, obs.EXE_HIT_EVENT)):
+            events[e] += 1
+
+    req = dataclasses.replace(_req(MIXED, "jax"), seed=12)
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        warm = planner.plan(req, bw).to_json()
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+    assert events == {obs.EXE_HIT_EVENT + "jax_engine.score": 2,
+                      obs.EXE_HIT_EVENT + "jax_engine.anneal": 2}
+    want = Planner(PipetteStrategy()).plan(
+        dataclasses.replace(_req(MIXED, "numpy"), seed=12), bw).to_json()
+    assert _strip_backend(warm) == _strip_backend(want)
 
 
 def test_pallas_interpret_matches_ref_kernels(monkeypatch):

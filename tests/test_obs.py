@@ -11,7 +11,7 @@ from repro.core import (Budget, Planner, PlanRequest, PipetteStrategy,
                         SearchSpace, Workload, build_profile,
                         profile_bandwidth)
 from repro.core.cluster import A100_TIER, V100_TIER, mixed_fleet_spec
-from repro.core.jax_engine import JaxDedicationEngine
+from repro.core.jax_engine import JaxDedicationEngine, clear_executables
 from repro.core.memory import enumerate_confs
 from repro.models.config import ModelConfig
 
@@ -41,6 +41,12 @@ def events():
     yield got
     jax.monitoring.unregister_event_duration_listener(on_duration)
     jax.monitoring.unregister_event_listener(on_event)
+
+
+@pytest.fixture(autouse=True)
+def _no_kept_executables():
+    """Each test starts as a fresh process would: nothing compiled."""
+    clear_executables()
 
 
 def _ours(events, prefix="/pipette/"):
@@ -97,9 +103,8 @@ def test_engine_counts_its_traces_not_its_calls(events):
     prof = build_profile(Workload(GPT, 2048, 32), MIXED, conf)
     rng = np.random.default_rng(0)
 
-    def traces():
-        return collections.Counter(n for n, _, _ in
-                                   _ours(events, obs.TRACE_EVENT))
+    def traces(prefix=obs.TRACE_EVENT):
+        return collections.Counter(n for n, _, _ in _ours(events, prefix))
 
     jeng = JaxDedicationEngine([conf], [prof], bw, MIXED)
     jeng.score(rng.permutation(MIXED.n_gpus))
@@ -107,9 +112,11 @@ def test_engine_counts_its_traces_not_its_calls(events):
     jeng.score_batch(np.stack([rng.permutation(MIXED.n_gpus)] * 2))
     jeng.score_batch(np.stack([rng.permutation(MIXED.n_gpus)] * 2))
     assert traces() == {"jax_engine.score": 1, "jax_engine.score_batch": 1}
+    # a second engine of the same shape reuses the executable
     JaxDedicationEngine([conf], [prof], bw, MIXED).score(
         rng.permutation(MIXED.n_gpus))
-    assert traces()["jax_engine.score"] == 2
+    assert traces() == {"jax_engine.score": 1, "jax_engine.score_batch": 1}
+    assert traces(obs.EXE_HIT_EVENT) == {"jax_engine.score": 1}
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
@@ -146,6 +153,7 @@ def test_overhead_reads_the_spans(events):
 def test_plan_event_count_does_not_depend_on_sa_iters(events):
     counts = []
     for iters in (50, 200):
+        clear_executables()
         events.clear()
         _plan("jax", sa_iters=iters)
         counts.append(collections.Counter(n for n, _, _ in _ours(events)))

@@ -100,6 +100,53 @@ def test_annealer_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in exe.as_text()
 
 
+def test_described_chip_executable_is_never_run_on_the_host(one_chip):
+    """The annealer's executable cache keys on each argument's sharding:
+    an engine compiled for the described chip and then run here traces
+    again, and matches an engine that never saw the described chip."""
+    from repro import obs
+    from repro.core import Conf, Workload, build_profile, profile_bandwidth
+    from repro.core.annealing import build_islands, make_move_plan
+    from repro.core.cluster import A100_TIER, V100_TIER, mixed_fleet_spec
+    from repro.core.jax_engine import JaxDedicationEngine, clear_executables
+    from repro.models.config import ModelConfig
+
+    gpt = ModelConfig(name="g12", family="dense", n_layers=12, d_model=1024,
+                      n_heads=16, n_kv_heads=16, d_ff=4096, vocab_size=32000)
+    spec = mixed_fleet_spec("exe-mixed-16x1", 16, (A100_TIER, V100_TIER),
+                            (0.5, 0.5), gpus_per_node=1, seed=31)
+    bw, _ = profile_bandwidth(spec)
+    conf = Conf(2, 2, 2, 1, 32, cp=2)
+    prof = build_profile(Workload(gpt, 2048, 32), spec, conf)
+    mp = make_move_plan([len(i) for i in build_islands(
+        spec, hierarchical=False)], 20, 2, 0)
+    inputs = (np.arange(spec.n_gpus)[None], mp.oa[None], mp.ob[None],
+              mp.kind, mp.thresh, mp.valid, mp.probe_oa[None],
+              mp.probe_ob[None], mp.probe_kind)
+    traces = []
+
+    def on_event(e, **kw):
+        if e.startswith(obs.TRACE_EVENT):
+            traces.append(e)
+
+    clear_executables()
+    jeng = JaxDedicationEngine([conf], [prof], bw, spec)
+    args = jeng.anneal_args(*inputs)
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        with jax.enable_x64(True):
+            on_chip = jeng.compile_anneal(_on(one_chip, args))
+        on_host = jeng.compile_anneal(args)
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+    assert on_host is not on_chip
+    assert traces == [obs.TRACE_EVENT + "jax_engine.anneal"] * 2
+    clear_executables()
+    want = JaxDedicationEngine([conf], [prof], bw, spec).anneal(*inputs)
+    assert all(np.array_equal(a, b)
+               for a, b in zip(jeng.anneal(*inputs), want))
+
+
 def test_train_step_fits_one_v5e(one_chip):
     """chip_smoke.py's train step: gpt-1.1b at full width and 12 of 24
     layers, seq 2048, global batch 8 in 2 microbatches, AdamW state in
