@@ -22,6 +22,9 @@ from typing import Any, Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from .. import obs
 
 
 @dataclass
@@ -41,29 +44,44 @@ class TrainRun:
 
 
 def plan_for_devices(cfg, seq_len: int, global_batch: int, *, seed: int = 0,
-                     devices=None):
-    """Profile this host's device links and run the Pipette search for them.
+                     devices=None, bw=None):
+    """Run the Pipette search for this host's devices.
+
+    ``bw`` is a recorded ``(n, n)`` bytes/s link matrix of ``devices``
+    (``inf`` on the diagonal); without it the links are profiled live
+    (:func:`~repro.core.cluster.profile_bandwidth_live`), so the plan
+    follows that profile.  The whole of it runs in the ``train.plan``
+    span.
 
     Returns ``(plan, spec, bw)``: the plan, the one-host spec priced as
     TPU v5e chips (:func:`~repro.core.cluster.live_host_spec`), and the
-    measured bandwidth matrix.
+    bandwidth matrix it was planned for.
     """
     from ..core import (Budget, ExhaustiveStrategy, Planner, PlanRequest,
                         PipetteStrategy, SearchSpace, Workload)
     from ..core.cluster import live_host_spec, profile_bandwidth_live
 
     devices = devices or jax.devices()
-    bw = profile_bandwidth_live(devices)
-    limit = (devices[0].memory_stats() or {}).get("bytes_limit")
-    spec = live_host_spec(bw, gpu_mem=limit)
-    req = PlanRequest(workload=Workload(cfg, seq_len, global_batch),
-                      spec=spec, space=SearchSpace(max_cp=1, max_vpp=1),
-                      budget=Budget(sa_seconds=60.0, sa_iters=2000,
-                                    backend="numpy"),
-                      seed=seed)
-    # one device has one mapping: rank configurations, anneal nothing
-    strategy = PipetteStrategy() if len(devices) > 1 else ExhaustiveStrategy()
-    return Planner(strategy).plan(req, bw), spec, bw
+    with obs.span("train.plan", devices=len(devices),
+                  recorded=bw is not None):
+        if bw is None:
+            bw = profile_bandwidth_live(devices)
+        bw = np.asarray(bw, dtype=float)
+        if bw.shape != (len(devices),) * 2:
+            raise ValueError(f"bandwidth matrix {bw.shape} does not match "
+                             f"{len(devices)} device(s)")
+        limit = (devices[0].memory_stats() or {}).get("bytes_limit")
+        spec = live_host_spec(bw, gpu_mem=limit)
+        req = PlanRequest(workload=Workload(cfg, seq_len, global_batch),
+                          spec=spec, space=SearchSpace(max_cp=1, max_vpp=1),
+                          budget=Budget(sa_seconds=60.0, sa_iters=2000,
+                                        backend="numpy"),
+                          seed=seed)
+        # one device has one mapping: rank configurations, anneal nothing
+        strategy = (PipetteStrategy() if len(devices) > 1
+                    else ExhaustiveStrategy())
+        plan = Planner(strategy).plan(req, bw)
+    return plan, spec, bw
 
 
 def train(cfg, *, steps: int, global_batch: int = 8, seq_len: int = 128,
@@ -99,13 +117,9 @@ def train(cfg, *, steps: int, global_batch: int = 8, seq_len: int = 128,
     opt = AdamW(lr=cosine_schedule(lr, min(20, max(1, steps // 5)), steps))
     loader = DataLoader(corpus or SyntheticCorpus(cfg.vocab_size, seed=seed),
                         LoaderConfig(global_batch, seq_len))
-    conf = mesh = None
     if plan is not None:
         n_micro = plan.conf.n_mb
-    if plan is not None and plan.conf.n_gpus > 1:
-        from .mesh import mesh_from_plan
-        conf, mesh = plan.conf, mesh_from_plan(plan)
-    layout = step_layout(cfg, opt, n_micro=n_micro, conf=conf, mesh=mesh)
+    _, layout = plan_layout(cfg, opt, plan, n_micro=n_micro)
     key = jax.random.PRNGKey(seed)
     params = jax.jit(layout.init, **layout.out(layout.params))(key)
     opt_state = jax.jit(opt.init, **layout.out(layout.opt_state))(params)
@@ -115,8 +129,8 @@ def train(cfg, *, steps: int, global_batch: int = 8, seq_len: int = 128,
 
     put_batch = layout.put_batch
     t0 = time.perf_counter()
-    compiled = layout.jit().lower(
-        params, opt_state, put_batch(loader.batch_at(0))).compile()
+    compiled = layout.compile(params, opt_state,
+                              put_batch(loader.batch_at(0)))
     compile_s = time.perf_counter() - t0
     log(f"[train] step compiled in {compile_s:.1f}s")
 
@@ -163,10 +177,35 @@ class StepLayout:
                else (self.params, self.opt_state, self.metrics))
         return jax.jit(self.step, donate_argnums=(0, 1), **self.out(out))
 
+    def compile(self, params, opt_state, batch):
+        """:meth:`jit`'s step lowered and compiled for these arguments
+        (``batch`` already placed by :meth:`put_batch`); fires the
+        ``launch.train_step`` trace counter once."""
+        obs.count_trace("launch.train_step")
+        return self.jit().lower(params, opt_state, batch).compile()
+
     def put_batch(self, batch):
         batch = {k: jnp.asarray(v) for k, v in batch.items()}
         return (jax.device_put(batch) if self.batch is None
                 else jax.device_put(batch, self.batch))
+
+
+def plan_layout(cfg, opt, plan, *, n_micro: int = 1):
+    """``(mesh, layout)``: the train step of ``cfg`` for ``plan``, built in
+    the ``train.layout`` span.  The step takes the plan's microbatch count
+    (``n_micro`` without a plan) and runs on
+    :func:`~repro.launch.mesh.mesh_from_plan`'s mesh, or on the default
+    device (``mesh`` None) without a plan or with a one-device plan."""
+    from .mesh import mesh_from_plan
+
+    with obs.span("train.layout"):
+        conf = mesh = None
+        if plan is not None:
+            n_micro = plan.conf.n_mb
+            if plan.conf.n_gpus > 1:
+                conf, mesh = plan.conf, mesh_from_plan(plan)
+        return mesh, step_layout(cfg, opt, n_micro=n_micro, conf=conf,
+                                 mesh=mesh)
 
 
 def step_layout(cfg, opt, *, n_micro: int, conf=None,

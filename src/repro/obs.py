@@ -1,4 +1,5 @@
-"""Spans and trace counters of the planner, on JAX's own sinks.
+"""Spans and trace counters of the planner and the train launcher, on
+JAX's own sinks.
 
 There is no buffer, exporter or switch here.  Every measurement goes to
 the two sinks JAX already has:

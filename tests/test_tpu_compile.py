@@ -3,10 +3,12 @@
 XLA's TPU compiler compiles for a topology that is described, not
 attached: these tests hand it the main path's device programs at real
 shapes and check what only it can refuse — Mosaic layouts of the Pallas
-group-reduce kernels, the float64 annealer, and whether the 12-layer
-gpt-1.1b train step fits one chip's HBM.  The topology is described only
-inside the fixtures below, so importing this module touches no TPU
-library, and every test skips where no topology can be described.
+group-reduce kernels, the float64 annealer, whether the 12-layer
+gpt-1.1b train step fits one chip's HBM, and whether the 28-layer
+Qwen2-1.5B step fits four chips at its planned layout.  The topology is
+described only inside the fixtures below, so importing this module
+touches no TPU library, and every test skips where no topology can be
+described.
 """
 import os
 
@@ -198,3 +200,41 @@ def test_pipelined_train_step_fits_four_v5e(topo):
     exe = lay.jit().lower(params, opt_state, batch).compile()
     assert "collective-permute" in exe.as_text()       # the stage hops
     assert exe.memory_analysis().peak_memory_in_bytes < V5E_HBM_BYTES
+
+
+def test_planned_qwen2_1_5b_step_fits_four_v5e(topo):
+    """Qwen2-1.5B at all 28 published layers in float32 with AdamW state,
+    seq 2048, global batch 4, at the layout its plan takes from the link
+    matrix recorded on a v5e 2x2 host (pp=4 with 4 microbatches; the
+    benchmark's ``train.qwen2-1.5b.plan4``): each chip's peak must fit the
+    15.75 GiB the runtime leaves a program."""
+    from jax.sharding import Mesh
+    from repro.core import Conf
+    from repro.launch.train import step_layout
+    from repro.models.config import ModelConfig
+    from repro.optim.adamw import AdamW
+
+    cfg = ModelConfig(name="qwen2-1.5b", family="dense", n_layers=28,
+                      d_model=1536, n_heads=12, n_kv_heads=2, d_ff=8960,
+                      vocab_size=151936, head_dim=128, qkv_bias=True,
+                      rope_theta=1e6, norm_eps=1e-6, tie_embeddings=True,
+                      dtype="float32")
+    opt = AdamW(lr=2e-4, b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1,
+                grad_clip=1.0)
+    conf = Conf(4, 1, 1, 1, 4)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1, 1),
+                ("pipe", "model", "data"))
+    lay = step_layout(cfg, opt, n_micro=conf.n_mb, conf=conf, mesh=mesh)
+
+    def on(shapes, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), shapes, shardings)
+
+    params = on(jax.eval_shape(lay.init, jax.random.PRNGKey(0)), lay.params)
+    opt_state = on(jax.eval_shape(opt.init, params), lay.opt_state)
+    batch = {k: jax.ShapeDtypeStruct((4, 2048), jnp.int32,
+                                     sharding=lay.batch)
+             for k in ("tokens", "labels")}
+    exe = lay.compile(params, opt_state, batch)
+    assert "collective-permute" in exe.as_text()       # the stage hops
+    assert exe.memory_analysis().peak_memory_in_bytes < 15.75 * 2**30
