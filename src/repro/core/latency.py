@@ -22,8 +22,8 @@ from typing import Optional, Sequence
 
 from .cluster import (ClusterSpec, compute_slowdowns, min_group_bw,
                       min_group_bw_batch, ring_allreduce_time)
-from .simulator import (Conf, Profile, default_mapping, dp_allreduce_times,
-                        dp_allreduce_times_ref, mapping4)
+from .simulator import (Conf, Profile, default_mapping, dp_allreduce_times_ref,
+                        hier_allreduce_batch, mapping4)
 
 
 def _tp_scale(conf: Conf, mapping: np.ndarray, bw: np.ndarray,
@@ -185,8 +185,14 @@ def _t_pp_chain_ref(conf: Conf, mapping: np.ndarray, bw: np.ndarray,
 
 def _t_dp_first_stage(conf: Conf, mapping: np.ndarray, bw: np.ndarray,
                       prof: Profile, spec: ClusterSpec) -> float:
-    """Eq. 6: hierarchical-ring all-reduce of stage 1, slowest tp group."""
-    return float(dp_allreduce_times(conf, mapping, bw, prof, spec)[0])
+    """Eq. 6: hierarchical-ring all-reduce of stage 1, slowest tp group.
+
+    Reduces only stage 0's ``tp * cp`` groups: bit-identical to
+    ``dp_allreduce_times(...)[0]``, which is the max over those same rows.
+    """
+    ids = mapping4(conf, mapping)[0].reshape(conf.tp * conf.cp, conf.dp)
+    t = hier_allreduce_batch(ids, np.asarray(bw), prof.msg_dp, spec)
+    return float(np.maximum(t.max(), 0.0))
 
 
 def _stage_compute_scale(conf: Conf, mapping: np.ndarray,
@@ -348,7 +354,7 @@ def default_mapping_latencies(confs: Sequence[Conf],
             scale = _tp_scale(conf, m, bw, spec, prof.tp_ref_bw)
             cscale = _cp_scale(conf, m, bw, prof.cp_ref_bw)
             hop = _pp_hop_bw(conf, m, bw) if conf.pp > 1 else None
-            t_dp = float(dp_allreduce_times(conf, m, bw, prof, spec)[0])
+            t_dp = _t_dp_first_stage(conf, m, bw, prof, spec)
             sscale = _stage_compute_scale(conf, m, spec)
             entry = cache[shape] = (scale, cscale, hop, t_dp, sscale,
                                     (prof.tp_ref_bw, prof.cp_ref_bw,

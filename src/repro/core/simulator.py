@@ -463,6 +463,12 @@ def hier_allreduce_batch(ids: np.ndarray, bw: np.ndarray, msg_bytes: float,
     sub-group's slowest link), then a phases=2 ring across one representative
     GPU per node (the first group member on each node).
 
+    Only the links the schedule reads are gathered: each member's same-node
+    partners and the representatives' block, O(m * gpus_per_node + n_reps^2)
+    per group instead of the dense ``(m, m)`` block.  Min and max are exact
+    in any order and each ring formula keeps the reference's operation
+    order, so the result does not depend on the gather's shape.
+
     Args:
         ids: ``(n_groups, m)`` GPU ids, one communicator group per row.
         bw: ``(G, G)`` bandwidth matrix in bytes/s.
@@ -477,28 +483,50 @@ def hier_allreduce_batch(ids: np.ndarray, bw: np.ndarray, msg_bytes: float,
     n_groups, m = ids.shape
     if m <= 1:
         return np.zeros(n_groups)
-    sub = bw[ids[:, :, None], ids[:, None, :]]            # (n_groups, m, m)
+    # Stable sort by node: each node's members become one contiguous run in
+    # group order, so a run's first entry is the reference's representative.
     node = ids // spec.gpus_per_node
-    same = node[:, :, None] == node[:, None, :]
-    eye = np.eye(m, dtype=bool)[None, :, :]
-    off = same & ~eye
-    # Per-member min over same-node links in both directions; the member that
-    # attains its node-cluster's global min reproduces the reference ring time
-    # exactly (the ring coefficient is constant inside a cluster).
-    masked = np.where(off, sub, np.inf)
-    member_min = np.minimum(masked.min(axis=2), masked.min(axis=1))
-    counts = same.sum(axis=2)                              # (n_groups, m)
+    order = np.argsort(node, axis=1, kind="stable")
+    ids = np.take_along_axis(ids, order, axis=1)
+    node = np.take_along_axis(node, order, axis=1)
+    is_rep = np.ones((n_groups, m), dtype=bool)
+    is_rep[:, 1:] = node[:, 1:] != node[:, :-1]
+
+    # Intra-node rings: every same-node pair lies at some offset d inside
+    # its run; charge its both-direction min to the earlier member.  The
+    # member holding its run's min reproduces the reference ring time
+    # exactly (the ring coefficient is constant inside a run, and division
+    # by a larger bandwidth never rounds to a larger time).
+    counts = np.ones((n_groups, m), dtype=np.intp)
+    member_min = np.full((n_groups, m), np.inf)
+    for d in range(1, m):
+        same = node[:, d:] == node[:, :-d]
+        if not same.any():
+            break                                   # runs are contiguous
+        a, b = ids[:, :-d], ids[:, d:]
+        link = np.where(same, np.minimum(bw[a, b], bw[b, a]), np.inf)
+        member_min[:, :-d] = np.minimum(member_min[:, :-d], link)
+        counts[:, :-d] += same
+        counts[:, d:] += same
     with np.errstate(divide="ignore", invalid="ignore"):
         intra_vals = 4 * (counts - 1) / counts * msg_bytes / member_min
     intra_t = np.where(counts > 1, intra_vals, 0.0).max(axis=1)
 
     # Representatives: first group member on each node (insertion order of the
     # reference dict) — membership matters because rep-to-rep links differ.
-    j_lt_i = np.arange(m)[None, None, :] < np.arange(m)[None, :, None]
-    is_rep = ~(same & j_lt_i).any(axis=2)
+    # Rows with fewer representatives than the widest row pad with inf.
     n_reps = is_rep.sum(axis=1)
-    pair = is_rep[:, :, None] & is_rep[:, None, :] & ~eye
-    rep_min = np.where(pair, sub, np.inf).min(axis=(1, 2))
+    width = int(n_reps.max())
+    first = np.argsort(~is_rep, axis=1, kind="stable")[:, :width]
+    reps = np.take_along_axis(ids, first, axis=1)
+    sub = bw[reps[:, :, None], reps[:, None, :]]      # (n_groups, w, w)
+    diag = np.arange(width)
+    sub[:, diag, diag] = np.inf
+    pad = diag[None, :] >= n_reps[:, None]
+    if pad.any():
+        sub[pad] = np.inf
+        sub.transpose(0, 2, 1)[pad] = np.inf
+    rep_min = sub.reshape(n_groups, -1).min(axis=1)
     with np.errstate(divide="ignore", invalid="ignore"):
         inter_vals = 2 * (n_reps - 1) / n_reps * msg_bytes / rep_min
     inter_t = np.where(n_reps > 1, inter_vals, 0.0)

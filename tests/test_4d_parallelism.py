@@ -8,11 +8,13 @@ from repro.core import (MID_RANGE, Conf, Workload, build_profile, configure,
                         default_mapping, dp_allreduce_times,
                         dp_allreduce_times_ref, enumerate_confs,
                         fit_memory_estimator, ground_truth_memory, measure,
-                        pipette_latency, pipette_latency_ref,
-                        profile_bandwidth, true_bandwidth_matrix)
+                        mixed_fleet_spec, pipette_latency,
+                        pipette_latency_ref, profile_bandwidth,
+                        true_bandwidth_matrix)
+from repro.core.cluster import A100_TIER, V100_TIER
 from repro.core.dedication import (DedicationEngine, GroupIndex, _move_span,
                                    perm_to_mapping)
-from repro.core.latency import default_mapping_latencies
+from repro.core.latency import _t_dp_first_stage, default_mapping_latencies
 from repro.core.simulator import ProfileCache, mapping4
 from repro.configs.gemma3_12b import CONFIG as GEMMA3
 from repro.models.config import ModelConfig
@@ -145,6 +147,28 @@ def test_cp_default_mapping_latencies_match_scalar():
     for i, (conf, prof) in enumerate(zip(confs, profiles)):
         assert batch[i] == pipette_latency(conf, default_mapping(conf), bw,
                                            prof, SPEC), str(conf)
+
+
+def test_default_mapping_latencies_match_reference_on_512_gpus():
+    """A 64-node x 8 two-tier fleet, where stage 0's DP groups span many
+    nodes: the pre-score's stage-0-only reduction over node blocks is
+    bit-equal to the pure-Python reference for every enumerated conf."""
+    spec = mixed_fleet_spec("two-tier-512", 64, (A100_TIER, V100_TIER),
+                            seed=3)
+    bw = true_bandwidth_matrix(spec)
+    w = Workload(GPT, SEQ, 512)
+    confs = enumerate_confs(spec.n_gpus, w.bs_global, n_layers=GPT.n_layers,
+                            max_tp=8, max_cp=2, seq=SEQ)
+    assert any(c.cp > 1 for c in confs) and any(c.dp >= 64 for c in confs)
+    cache = ProfileCache(w, spec)
+    profiles = [cache.get(c) for c in confs]
+    batch = default_mapping_latencies(confs, profiles, bw, spec)
+    for i, (conf, prof) in enumerate(zip(confs, profiles)):
+        m = default_mapping(conf)
+        assert batch[i] == pipette_latency_ref(conf, m, bw, prof, spec), \
+            str(conf)
+        assert _t_dp_first_stage(conf, m, bw, prof, spec) == \
+            dp_allreduce_times_ref(conf, m, bw, prof, spec)[0], str(conf)
 
 
 def test_mapping4_accepts_legacy_and_4d_shapes():

@@ -1,15 +1,20 @@
 """Vectorized dedication engine: bit-exact equivalence against the
 pure-Python reference scorer, incremental delta-scoring correctness, and
 multi-start determinism."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
-from repro.core import (MID_RANGE, Conf, Workload, anneal, anneal_multistart,
-                        build_profile, dp_allreduce_times,
-                        dp_allreduce_times_ref, pipette_latency,
-                        pipette_latency_ref, true_bandwidth_matrix)
+from repro.core import (MID_RANGE, ClusterSpec, Conf, Workload, anneal,
+                        anneal_multistart, build_profile, dp_allreduce_times,
+                        dp_allreduce_times_ref, mixed_fleet_spec,
+                        pipette_latency, pipette_latency_ref,
+                        true_bandwidth_matrix)
+from repro.core.cluster import A100_TIER, V100_TIER
 from repro.core.dedication import DedicationEngine, GroupIndex, _move_span, \
     perm_to_mapping
+from repro.core.simulator import hier_allreduce_batch, mapping4
 from repro.models.config import ModelConfig
 
 GPT = ModelConfig(name="g", family="dense", n_layers=24, d_model=1920,
@@ -48,6 +53,105 @@ def test_vectorized_dp_allreduce_matches_reference_exactly():
         vec = dp_allreduce_times(conf, mapping, bw, prof, spec)
         ref = dp_allreduce_times_ref(conf, mapping, bw, prof, spec)
         assert np.array_equal(vec, ref), (trial, str(conf))
+
+
+def _allreduce_spec(gpn, tiered, n_gpus=32):
+    if tiered:
+        return mixed_fleet_spec("two-tier", n_gpus // gpn,
+                                (A100_TIER, V100_TIER), gpus_per_node=gpn,
+                                seed=7)
+    return ClusterSpec("flat", n_gpus // gpn, gpus_per_node=gpn, seed=7)
+
+
+def _jittered_bw(spec, rng):
+    """Per-link noise on top of the node-pair bandwidths, so that which GPU
+    represents a node changes the inter-node bottleneck."""
+    bw = true_bandwidth_matrix(spec)
+    return bw * rng.uniform(0.5, 1.5, bw.shape)
+
+
+def _row_ref(row, bw, msg, spec):
+    """The scalar reference's all-reduce seconds of one group."""
+    row = np.asarray(row)
+    conf = Conf(1, 1, len(row), 1, len(row))
+    return dp_allreduce_times_ref(conf, row.reshape(1, 1, -1), bw,
+                                  SimpleNamespace(msg_dp=msg), spec)[0]
+
+
+@pytest.mark.parametrize("cp", [1, 2])
+@pytest.mark.parametrize("tiered", [False, True], ids=["flat", "two_tier"])
+@pytest.mark.parametrize("gpn", [1, 2, 8])
+def test_dp_allreduce_node_blocks_match_reference(gpn, tiered, cp):
+    """Every (pp, tp, dp) shape of a 32-GPU fleet under random permutation
+    mappings (ragged per-node counts): per-stage times and per-group rows
+    are bit-equal to the scalar reference."""
+    spec = _allreduce_spec(gpn, tiered)
+    rng = np.random.default_rng(gpn * 10 + cp + 4 * tiered)
+    bw = _jittered_bw(spec, rng)
+    shapes = [(pp, tp, 32 // (pp * tp * cp)) for pp in (1, 2, 4)
+              for tp in (1, 2, 4, 8) if 32 % (pp * tp * cp) == 0]
+    for pp, tp, dp in shapes:
+        conf = Conf(pp, tp, dp, 1, 4 * dp, cp=cp)
+        prof = build_profile(Workload(GPT, 512, conf.bs_global), spec, conf)
+        for _ in range(3):
+            mapping = perm_to_mapping(rng.permutation(32), conf)
+            vec = dp_allreduce_times(conf, mapping, bw, prof, spec)
+            ref = dp_allreduce_times_ref(conf, mapping, bw, prof, spec)
+            assert np.array_equal(vec, ref), (str(conf), vec - ref)
+            ids = mapping4(conf, mapping).reshape(-1, dp)
+            rows = hier_allreduce_batch(ids, bw, prof.msg_dp, spec)
+            assert [max(t, 0.0) for t in rows] == \
+                [_row_ref(r, bw, prof.msg_dp, spec) for r in ids]
+
+
+# Groups on 4 nodes of 8: rows of one batch differ in node counts, so the
+# representatives' block is padded for all but the widest row.
+_GROUPS = {
+    "inside_one_node": [[0, 1, 2, 3, 4, 5, 6, 7], [15, 9, 13, 8, 10, 11, 12, 14]],
+    "one_per_node": [[0, 8, 16, 24], [31, 7, 23, 15]],
+    "ragged": [[3, 0, 9, 17, 18, 1, 30], [8, 9, 10, 11, 12, 13, 20],
+               [26, 5, 14, 2, 31, 24, 25]],
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_GROUPS))
+def test_hier_allreduce_group_shapes_match_reference(shape):
+    spec = MID_RANGE.with_nodes(4)
+    bw = _jittered_bw(spec, np.random.default_rng(5))
+    ids = np.asarray(_GROUPS[shape])
+    rows = hier_allreduce_batch(ids, bw, 3e8, spec)
+    assert list(rows) == [_row_ref(r, bw, 3e8, spec) for r in ids]
+
+
+def test_hier_allreduce_zero_link_prices_inf():
+    """A zero link on a ring's path prices that group at inf (the scalar
+    reference refuses it); groups that avoid the link are unchanged."""
+    spec = MID_RANGE.with_nodes(4)
+    bw = true_bandwidth_matrix(spec)
+    bw[1, 2] = 0.0                      # intra-node, group 0 only
+    bw[16, 24] = 0.0                    # representatives of group 1
+    ids = np.asarray([[0, 1, 2, 8], [16, 24, 17, 25], [4, 12, 20, 28]])
+    rows = hier_allreduce_batch(ids, bw, 3e8, spec)
+    assert np.isinf(rows[0]) and np.isinf(rows[1])
+    for r in ids[:2]:
+        with pytest.raises(ValueError):
+            _row_ref(r, bw, 3e8, spec)
+    assert rows[2] == _row_ref(ids[2], bw, 3e8, spec)
+
+
+def test_hier_allreduce_inf_link():
+    """An inf link that is nobody's bottleneck changes nothing; a ring
+    whose every link is inf prices at 0 (the scalar reference refuses)."""
+    spec = MID_RANGE.with_nodes(4)
+    bw = true_bandwidth_matrix(spec)
+    bw[0, 1] = bw[8, 16] = np.inf
+    bw[4, 5] = bw[5, 4] = np.inf
+    row = [0, 1, 2, 8, 16]
+    assert hier_allreduce_batch(np.asarray([row]), bw, 3e8, spec)[0] == \
+        _row_ref(row, bw, 3e8, spec) > 0
+    assert hier_allreduce_batch(np.asarray([[4, 5]]), bw, 3e8, spec)[0] == 0
+    with pytest.raises(ValueError):
+        _row_ref([4, 5], bw, 3e8, spec)
 
 
 def test_engine_full_score_matches_latency():
