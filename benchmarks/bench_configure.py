@@ -59,8 +59,8 @@ import time
 
 import numpy as np
 
-from repro.core import (MID_RANGE, ProfileCache, Workload,
-                        anneal_multistart, build_profile, configure,
+from repro.core import (MID_RANGE, Budget, ProfileCache, Workload,
+                        build_profile, configure, dedicate_candidates,
                         enumerate_confs, fit_memory_estimator,
                         true_bandwidth_matrix)
 from repro.core.cluster import (A100_TIER, V100_TIER, mixed_fleet_spec,
@@ -188,11 +188,11 @@ def bench_hetero_dedication(*, quick: bool):
     bw_true = true_bandwidth_matrix(spec)
     prof = build_profile(w, spec, conf)
     iters = 10_000 if quick else 40_000
-    kw = dict(n_chains=4, time_limit_s=60.0, max_iters=iters, seed=0)
+    budget = Budget(sa_seconds=60.0, sa_iters=iters, n_chains=4)
     t0 = time.perf_counter()
-    aware = anneal_multistart(conf, bw, prof, spec, **kw)
-    blind = anneal_multistart(conf, bw, prof, spec, compute_aware=False,
-                              **kw)
+    aware = dedicate_candidates([conf], [prof], [0], bw, spec, budget, 0)[0]
+    blind = dedicate_candidates([conf], [prof], [0], bw, spec, budget, 0,
+                                compute_aware=False)[0]
     wall = time.perf_counter() - t0
     sim_aware = measure(conf, aware.mapping, w, spec, bw_true, seed=1)
     sim_blind = measure(conf, blind.mapping, w, spec, bw_true, seed=1)

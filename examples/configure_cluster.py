@@ -19,7 +19,7 @@ from repro.core import (HIGH_END, MID_RANGE, MID_RANGE_DEGRADED,
                         AMPStrategy, Budget, ExhaustiveStrategy,
                         MegatronStrategy, Planner, PlanRequest,
                         PipetteStrategy, SearchSpace, VarunaStrategy,
-                        Workload, anneal_multistart, build_profile,
+                        Workload, build_profile, dedicate_candidates,
                         fit_memory_estimator, ground_truth_memory, measure,
                         profile_bandwidth, true_bandwidth_matrix)
 from repro.configs.gpt_paper import GPT_3_1B, GPT_11_1B
@@ -61,10 +61,11 @@ def degraded_host_demo(base_w, spec, bw_meas, bw_true, *, seed=0):
     slow = compute_slowdowns(spec)
     # compute-aware placement: fastest GPUs serve the heavy leading stages,
     # throttled hosts sink to the light trailing ones; SA polishes comm
-    greedy = np.argsort(slow, kind="stable")
-    aware = anneal_multistart(conf, bw_meas, prof, spec, n_chains=2,
-                              time_limit_s=10.0, max_iters=10_000,
-                              seed=seed, init_perm=greedy)
+    greedy = tuple(int(g) for g in np.argsort(slow, kind="stable"))
+    budget = Budget(sa_seconds=10.0, sa_iters=10_000, n_chains=2,
+                    warm_start=greedy)
+    aware = dedicate_candidates([conf], [prof], [0], bw_meas, spec, budget,
+                                seed)[0]
     sim_aware = measure(conf, aware.mapping, w, spec, bw_true, seed=1)
     from repro.core import default_mapping
     sim_blind = measure(conf, default_mapping(conf), w, spec, bw_true,

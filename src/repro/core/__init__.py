@@ -47,8 +47,7 @@ from .memory import (MemoryEstimator, analytical_estimate, enumerate_confs,
                      fit_memory_estimator, ground_truth_memory, mape,
                      rank_state_bytes)
 from .dedication import (DedicationEngine, GroupIndex, PairCache, SAResult,
-                         anneal, anneal_multistart, mapping_to_perm,
-                         perm_to_mapping, project_perm)
+                         mapping_to_perm, perm_to_mapping, project_perm)
 from .migration import (DEFAULT_RESTART_S, PlanDiff, diff_assignments,
                         resolve_model, state_keys)
 from .annealing import (MovePlan, build_islands, coarse_assign,

@@ -1,27 +1,29 @@
-"""Backend-selectable multi-chain SA core with hierarchical island search.
+"""Stage 5 of the search: the one SA dedication driver, two executors.
 
-This is the unified dedication driver behind ``Budget(backend=...)``:
-the full move schedule of every chain — move kinds, positions, accept
-thresholds, per-chain iteration budgets — is precomputed on the host as a
-:class:`MovePlan`, and then *executed* by one of two interchangeable
-engines:
+:func:`dedicate_candidates` precomputes the full move schedule of every
+chain — move kinds, positions, accept thresholds, per-chain iteration
+budgets — on the host as a :class:`MovePlan`, and then *executes* it with
+one of two engines, selected by ``Budget.backend``:
 
-* ``backend="numpy"`` — the incremental
+* ``"numpy"`` (the default) — the incremental
   :class:`~repro.core.dedication.DedicationEngine`, one Python loop per
   chain (fast at small fleets, where per-move work is tiny);
-* ``backend="jax"`` — :class:`~repro.core.jax_engine.JaxDedicationEngine`,
+* ``"jax"`` — :class:`~repro.core.jax_engine.JaxDedicationEngine`,
   a jitted ``lax.scan`` vmapped across chains *and* same-shape candidate
   configurations (fast at large fleets, where the vectorized full
   re-score amortises and Python dispatch would dominate).
 
 Because the RNG stream lives entirely in the MovePlan and both engines
 score bit-identically on CPU (float64 everywhere, matching reduction
-order), the two backends produce **byte-identical plans** there, chain
-for chain — pinned by ``tests/test_backend_determinism.py``.  On TPU the
-JAX scores are within ``jax_engine.TPU_REL_TOL`` of the reference.
-``backend=None`` (the default) is not handled here at all: ``run_search``
-keeps the historical per-candidate ``anneal``/``anneal_multistart`` path,
-bit-exact with its regression fixtures.
+order), the two executors produce **byte-identical plans** there, chain
+for chain, whenever the wall-clock budget does not bite — pinned by
+``tests/test_backend_determinism.py``.  On TPU the JAX scores are within
+``jax_engine.TPU_REL_TOL`` of the reference.
+
+``Budget.sa_seconds`` binds only the NumPy executor: chain ``k`` of a
+candidate stops once ``sa_seconds / n_chains`` seconds have passed since
+it started, checked before every move.  The JAX executor is
+iteration-bound (one dispatch cannot be interrupted).
 
 Scale comes from the *hierarchical* mode layered on top: nodes are
 clustered into tier/bandwidth islands (:func:`build_islands`), the
@@ -35,12 +37,10 @@ decomposition degenerates to the flat path bit-exactly: the identity
 ordering is the only coarse candidate and the MovePlan draws identical
 streams (the island-selection draw is skipped when there is only one).
 
-Budget split across chains (also the :func:`~repro.core.dedication.
-anneal_multistart` contract after the fix shipped with this module): with
-``base, rem = divmod(sa_iters, n_chains)``, chain ``k`` runs
-``base + 1`` iterations if ``k < rem`` else ``base`` — totals are exact,
-and chains beyond ``sa_iters`` run zero moves, contributing the initial
-permutation's score.
+Budget split across chains: with ``base, rem = divmod(sa_iters,
+n_chains)``, chain ``k`` runs ``base + 1`` iterations if ``k < rem`` else
+``base`` — totals are exact, and chains beyond ``sa_iters`` run zero
+moves, contributing the initial permutation's score.
 """
 from __future__ import annotations
 
@@ -61,8 +61,8 @@ from .simulator import Conf, Profile
 #: below this the flat path is still competitive and simpler to audit).
 HIER_AUTO_GPUS = 2048
 
-#: Temperature probes per chain (the initial-temperature estimate of
-#: ``dedication.anneal``, kept at the same count).
+#: Temperature probes per chain (the initial temperature is the largest
+#: probe delta).
 N_PROBES = 8
 
 #: Island size cap in GPUs: islands are chunks of whole same-tier nodes
@@ -303,13 +303,15 @@ def _move_numpy(perm: np.ndarray, kind: int, pa: int,
 
 def _run_chain_numpy(engine: DedicationEngine, init_perm: np.ndarray,
                      offsets: np.ndarray, plan: MovePlan, k: int,
-                     alpha: float):
+                     alpha: float, deadline: float = float("inf")):
     """Execute chain ``k`` of ``plan`` with the incremental NumPy engine.
 
     Bit-for-bit the computation ``JaxDedicationEngine.anneal`` performs for
     the same chain: same probes, same ``temp0 = max(max|delta|,
     cur*1e-3, 1e-12)``, same accept rule ``delta <= 0 or
-    delta < temp * thresh``, same best-so-far tracking.
+    delta < temp * thresh``, same best-so-far tracking — unless the chain
+    reaches ``deadline`` (a ``time.perf_counter()`` value, checked before
+    every move) first; it then stops and reports the moves it ran.
     """
     iters_k = int(plan.chain_iters[k])
     perm = init_perm.copy()
@@ -328,6 +330,8 @@ def _run_chain_numpy(engine: DedicationEngine, init_perm: np.ndarray,
     temp = max(mx, cur * 1e-3, 1e-12)
     acc = acc_best = 0
     for t in range(iters_k):
+        if time.perf_counter() >= deadline:
+            return best, best_perm, t, acc, acc_best
         off = offsets[plan.isl[k, t]]
         cand, touched = _move_numpy(perm, int(plan.kind[k, t]),
                                     int(off + plan.oa[k, t]),
@@ -377,39 +381,40 @@ def dedicate_candidates(survivors: Sequence[Conf],
                         sa_idx: Sequence[int], bw: np.ndarray,
                         spec: ClusterSpec, budget, seed: int, *,
                         compute_aware: bool = True) -> Dict[int, SAResult]:
-    """Stage-5 dedication through the unified backend-selectable core.
+    """Stage-5 dedication: SA on the survivors in ``sa_idx``.
 
-    Runs SA dedication for the survivor indices in ``sa_idx`` and returns
-    ``{index: SAResult}``.  Candidates are grouped by (pp, tp, cp, dp, vpp)
-    shape; the ``"jax"`` backend advances every chain of every candidate
-    in a group with one vmapped dispatch, the ``"numpy"`` backend loops —
-    both execute the identical :class:`MovePlan`, so results are
-    byte-identical (see module docstring).
-
-    ``budget.sa_seconds`` is a per-candidate wall-clock guard on the NumPy
-    backend (chains still pending when it expires contribute the coarse
-    permutation's score, like the historical driver); the JAX backend is
-    iteration-bound only — a single dispatch cannot be interrupted — so
-    byte-parity across backends holds whenever the time guard does not
-    bite (use iteration-bound budgets for reproducible plans, as the
-    golden tests do).
+    Returns ``{index: SAResult}``.  Candidates are grouped by (pp, tp, cp,
+    dp, vpp) shape; the ``"jax"`` executor advances every chain of every
+    candidate in a group with one vmapped dispatch, the ``"numpy"``
+    executor loops — both execute the identical :class:`MovePlan`, so
+    results are byte-identical while ``budget.sa_seconds`` does not bite
+    (see module docstring for the wall-clock rule of each executor; use
+    iteration-bound budgets for reproducible plans, as the golden tests
+    do).
 
     ``budget.warm_start`` (a flat GPU permutation, e.g. recovered from a
     cached neighbour Plan via :func:`~repro.core.dedication.
-    mapping_to_perm`) seeds every candidate's chains from the incumbent
-    arrangement instead of the coarse assignment whenever the incumbent
-    scores strictly better — the same comparison on both backends (their
-    scorers are bit-identical), so warm-started plans keep byte parity
-    too.  SA tracks best-so-far from the chosen init, so a warm-started
-    candidate can never score worse than its seed permutation.
+    mapping_to_perm`) must be a permutation of the cluster's GPU ids.  It
+    seeds every candidate's chains from the incumbent arrangement instead
+    of the coarse assignment whenever the incumbent scores strictly
+    better — the same comparison on both executors (their scorers are
+    bit-identical), so warm-started plans keep byte parity too.  SA tracks
+    best-so-far from the chosen init, so a warm-started candidate can
+    never score worse than its seed permutation.
+
+    ``compute_aware=False`` prices every GPU at reference speed (the
+    compute-blind baseline of the heterogeneous evaluation).
     """
     backend = budget.backend
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unified driver needs backend numpy|jax, "
-                         f"got {backend!r}")
-    warm = getattr(budget, "warm_start", None)
-    warm_perm = (None if warm is None
-                 else np.asarray(warm, dtype=np.int64))
+    warm_perm: Optional[np.ndarray] = None
+    if budget.warm_start is not None:
+        warm_perm = np.asarray(budget.warm_start, dtype=np.int64)
+        n = spec.n_gpus
+        if (warm_perm.shape != (n,)
+                or not np.array_equal(np.sort(warm_perm), np.arange(n))):
+            raise ValueError(
+                f"budget.warm_start must be a permutation of the {n} "
+                f"cluster GPU ids, got shape {warm_perm.shape}")
 
     def pick_init(scorer, coarse):
         """Coarse assignment vs warm incumbent — strictly-better wins,
@@ -499,27 +504,22 @@ def dedicate_candidates(survivors: Sequence[Conf],
                                                      orderings))
                           for i in idxs}
             with obs.span("sa.anneal", group=g):
+                chain_s = budget.sa_seconds / plan.n_chains
                 for i in idxs:
                     tc = time.perf_counter()
-                    deadline = tc + budget.sa_seconds
                     init_perm, offsets, cval = coarse[i]
-                    lats, perms, iters, accs, accbs = [], [], 0, [], []
-                    for k in range(plan.n_chains):
-                        if time.perf_counter() >= deadline and lats:
-                            break              # out of wall-clock budget
-                        b, p, it, ac, ab = _run_chain_numpy(
-                            engines[i], init_perm, offsets, plan, k, _ALPHA)
-                        lats.append(b)
-                        perms.append(p)
-                        iters += it
-                        accs.append(ac)
-                        accbs.append(ab)
+                    runs = [_run_chain_numpy(engines[i], init_perm, offsets,
+                                             plan, k, _ALPHA,
+                                             time.perf_counter() + chain_s)
+                            for k in range(plan.n_chains)]
+                    lats = [float(r[0]) for r in runs]
+                    iters = sum(r[2] for r in runs)  # repro: noqa DET004 -- iteration counts are ints; integer addition is order-independent
+                    accs = sum(r[3] for r in runs)  # repro: noqa DET004 -- accepted-move counters are ints; integer addition is order-independent
                     win = int(np.argmin(lats))
-                    results[i] = _to_result(survivors[i], perms[win],
-                                            float(lats[win]), cval, iters,
-                                            time.perf_counter() - tc,
-                                            [float(v) for v in lats],
-                                            sum(accs), accbs[win])  # repro: noqa DET004 -- accepted-move counters are ints; integer addition is order-independent
+                    results[i] = _to_result(survivors[i], runs[win][1],
+                                            lats[win], cval, iters,
+                                            time.perf_counter() - tc, lats,
+                                            accs, runs[win][4])
     return results
 
 

@@ -1,13 +1,10 @@
-"""Fine-grained worker dedication (§IV): simulated annealing over the 1:1
-logical-worker -> GPU mapping.
+"""Fine-grained worker dedication (§IV): the 1:1 logical-worker -> GPU
+mapping, its permutation form, and the incremental scorer the host SA
+executor runs.
 
-Moves (paper §IV): *migration* (remove one element, reinsert at a random
-position), *swap* (exchange two elements) and *reverse* (reverse a
-substring — exploits the near-symmetric bidirectional bandwidths).
-Temperature decay alpha = 0.999; the budget is wall-clock seconds with an
-iteration cap so tests stay fast.
-
-The hot loop is driven by :class:`DedicationEngine`, an incremental
+The annealing itself — moves (paper §IV: *migration*, *swap*, *reverse*),
+temperature schedule and budgets — lives in :mod:`repro.core.annealing`.
+Its NumPy executor is driven by :class:`DedicationEngine`, an incremental
 vectorized scorer: the three SA moves touch a known set of permutation
 positions, and only the TP groups / pipeline chains / first-stage DP groups
 (and, for 4D configurations, the context-parallel ring groups; on tiered
@@ -15,19 +12,16 @@ clusters, the pipeline stages whose compute-slowness changed) containing
 those positions are re-gathered and re-reduced — everything else
 comes from per-group caches.  Scores are bit-identical to the full
 :func:`repro.core.latency.pipette_latency` (and its pure-Python reference).
-:func:`anneal_multistart` adds best-of-``n_chains`` restarts on top.
 """
 from __future__ import annotations
 
-import math
-import time
 from dataclasses import dataclass
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
 import numpy as np
 
 from .cluster import ClusterSpec, compute_slowdowns
-from .latency import _hetero_combine, pipette_latency
+from .latency import _hetero_combine
 from .simulator import Conf, Profile
 
 
@@ -139,7 +133,8 @@ class SAResult:
             fewer accepted moves than a cold chain.
 
     Example:
-        >>> res = anneal(conf, bw, prof, spec, time_limit_s=0.5, seed=0)
+        >>> res = dedicate_candidates([conf], [prof], [0], bw, spec,
+        ...                           Budget(), seed=0)[0]
         >>> res.latency <= pipette_latency(conf, default_mapping(conf),
         ...                                bw, prof, spec)
         True
@@ -155,51 +150,6 @@ class SAResult:
     chain_latencies: Optional[List[float]] = None
     accepted: int = 0
     accepted_to_best: int = 0
-
-
-# ---------------------------------------------------------------------------
-# moves
-# ---------------------------------------------------------------------------
-
-def _move_span(perm: np.ndarray,
-               rng: np.random.Generator) -> Tuple[np.ndarray, np.ndarray]:
-    """One SA move plus the positions it touched.
-
-    Returns:
-        ``(new_perm, touched)`` where ``touched`` is the array of permutation
-        positions whose GPU changed (a superset is allowed; migration and
-        reverse report the contiguous affected span, swap exactly two).
-    """
-    n = len(perm)
-    p = perm.copy()
-    kind, i, j = (int(v) for v in rng.integers((3, n, n - 1)))
-    if j >= i:
-        j += 1
-    if i > j:
-        i, j = j, i
-    if kind == 0:          # migration: remove at i, reinsert at j % (n-1)
-        jj = j % (n - 1)
-        el = p[i]
-        if jj >= i:
-            p[i:jj] = p[i + 1:jj + 1].copy()
-            p[jj] = el
-            touched = np.arange(i, jj + 1)
-        else:
-            p[jj + 1:i + 1] = p[jj:i].copy()
-            p[jj] = el
-            touched = np.arange(jj, i + 1)
-    elif kind == 1:        # swap
-        p[i], p[j] = p[j], p[i]
-        touched = np.array((i, j))
-    else:                  # reverse
-        p[i:j + 1] = p[i:j + 1][::-1]
-        touched = np.arange(i, j + 1)
-    return p, touched
-
-
-def _move(perm: np.ndarray, rng: np.random.Generator) -> np.ndarray:
-    """One SA move (migration / swap / reverse); returns the new permutation."""
-    return _move_span(perm, rng)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -337,7 +287,7 @@ class DedicationEngine:
     Example:
         >>> eng = DedicationEngine(conf, bw, prof, spec)
         >>> cur = eng.score(np.arange(conf.n_gpus))
-        >>> cand, touched = _move_span(np.arange(conf.n_gpus), rng)
+        >>> cand, touched = _move_numpy(np.arange(conf.n_gpus), 1, 0, 3)
         >>> val, pending = eng.propose(cand, touched)
         >>> eng.commit(pending)          # accept the move
     """
@@ -599,142 +549,3 @@ class DedicationEngine:
         """Promote a :meth:`propose` result to the committed state."""
         (self._tp_vals, self._chain_vals, self._dp0_vals,
          self._cp_vals, self._stage_vals) = pending
-
-
-# ---------------------------------------------------------------------------
-# annealing drivers
-# ---------------------------------------------------------------------------
-
-def anneal(conf: Conf, bw: np.ndarray, prof: Profile, spec: ClusterSpec, *,
-           objective: Optional[Callable[[np.ndarray], float]] = None,
-           time_limit_s: float = 2.0, max_iters: int = 20_000,
-           alpha: float = 0.999, seed: int = 0,
-           init_perm: Optional[np.ndarray] = None,
-           engine: Optional[DedicationEngine] = None,
-           compute_aware: bool = True) -> SAResult:
-    """Simulated-annealing worker dedication (Algorithm 1, line 7).
-
-    Args:
-        conf: parallelism configuration to dedicate workers for.
-        bw: ``(G, G)`` profiled bandwidth matrix, bytes/s.
-        prof: profiled per-microbatch quantities.
-        spec: cluster description.
-        objective: optional custom ``perm -> cost``; when given, the generic
-            (non-incremental) path is used.  Default scores with the
-            incremental :class:`DedicationEngine` — same values, ~10-100x
-            more moves/sec.
-        time_limit_s: wall-clock budget.
-        max_iters: iteration cap (keeps tests fast).
-        alpha: geometric temperature decay per move.
-        seed: RNG seed; runs are deterministic given (seed, inputs).
-        init_perm: starting permutation (identity when ``None``).
-        engine: reuse a pre-built engine (e.g. shared index tensors).
-        compute_aware: forwarded to :class:`DedicationEngine` when one is
-            built here; ``False`` anneals compute-blind on tiered specs
-            (ignored when ``engine`` is given).
-
-    Returns:
-        :class:`SAResult` with the best mapping found and its trace.
-    """
-    rng = np.random.default_rng(seed)
-    n = conf.n_gpus
-    perm = np.arange(n) if init_perm is None else init_perm.copy()
-
-    use_engine = objective is None
-    if use_engine:
-        if engine is None:
-            engine = DedicationEngine(conf, bw, prof, spec,
-                                      compute_aware=compute_aware)
-        cur = engine.score(perm)
-    else:
-        cur = objective(perm)
-
-    best_perm, best = perm.copy(), cur
-    # initial temperature from the spread of a few random proposals
-    probes = []
-    for _ in range(8):
-        cand, touched = _move_span(perm, rng)
-        val = engine.propose(cand, touched)[0] if use_engine \
-            else objective(cand)
-        probes.append(abs(val - cur))
-    temp = max(max(probes), cur * 1e-3, 1e-12)
-
-    t0 = time.perf_counter()
-    it = 0
-    acc = acc_best = 0
-    trace = [(0, best)]
-    while it < max_iters and (time.perf_counter() - t0) < time_limit_s:
-        cand, touched = _move_span(perm, rng)
-        if use_engine:
-            val, pending = engine.propose(cand, touched)
-        else:
-            val = objective(cand)
-        delta = val - cur
-        if delta <= 0 or rng.random() < math.exp(-delta / max(temp, 1e-15)):
-            perm, cur = cand, val
-            acc += 1
-            if use_engine:
-                engine.commit(pending)
-            if cur < best:
-                best_perm, best = perm.copy(), cur
-                acc_best = acc
-                trace.append((it, best))
-        temp *= alpha
-        it += 1
-    return SAResult(perm_to_mapping(best_perm, conf), best_perm, best, it,
-                    time.perf_counter() - t0, trace,
-                    accepted=acc, accepted_to_best=acc_best)
-
-
-def anneal_multistart(conf: Conf, bw: np.ndarray, prof: Profile,
-                      spec: ClusterSpec, *, n_chains: int = 4,
-                      time_limit_s: float = 2.0, max_iters: int = 20_000,
-                      alpha: float = 0.999, seed: int = 0,
-                      init_perm: Optional[np.ndarray] = None,
-                      engine: Optional[DedicationEngine] = None,
-                      compute_aware: bool = True) -> SAResult:
-    """Best-of-``n_chains`` independent annealing restarts.
-
-    The budgets are split across chains so the total cost matches a single
-    :func:`anneal` call with the same budgets — *exactly*: with
-    ``base, rem = divmod(max_iters, n_chains)``, chain ``k`` runs
-    ``base + 1`` iterations when ``k < rem`` else ``base`` (the historical
-    ``max(1, max_iters // n_chains)`` silently ran up to ``n_chains - 1``
-    extra iterations, and a full ``n_chains`` extra when
-    ``n_chains > max_iters``).  Edge cases are defined, not accidental:
-    a chain whose share is zero iterations runs no moves and contributes
-    its initial permutation's score; ``time_limit_s = 0`` gives every
-    chain a zero wall-clock budget, so all chains are score-only and the
-    result is the initial permutation.  Chain ``k`` runs with seed
-    ``seed * 100003 + k``, making the whole driver deterministic in
-    ``seed``.
-
-    Returns:
-        :class:`SAResult` of the winning chain, with ``iters``/``seconds``
-        summed over all chains and ``chain_latencies`` listing every chain's
-        best.
-    """
-    if n_chains < 1:
-        raise ValueError("n_chains must be >= 1")
-    if engine is None:
-        engine = DedicationEngine(conf, bw, prof, spec,
-                                  compute_aware=compute_aware)
-    per_t = time_limit_s / n_chains
-    base_it, rem_it = divmod(max_iters, n_chains)
-    best: Optional[SAResult] = None
-    iters, seconds, lats, acc = 0, 0.0, [], 0
-    for k in range(n_chains):
-        res = anneal(conf, bw, prof, spec, time_limit_s=per_t,
-                     max_iters=base_it + (1 if k < rem_it else 0),
-                     alpha=alpha,
-                     seed=seed * 100003 + k, init_perm=init_perm,
-                     engine=engine)
-        iters += res.iters
-        seconds += res.seconds
-        lats.append(res.latency)
-        acc += res.accepted
-        if best is None or res.latency < best.latency:
-            best = res
-    return SAResult(best.mapping, best.perm, best.latency, iters, seconds,
-                    best.trace, chain_latencies=lats, accepted=acc,
-                    accepted_to_best=best.accepted_to_best)

@@ -1,11 +1,13 @@
 """JAX-native dedication scorer and vmapped multi-chain annealer.
 
-This is the ``backend="jax"`` execution engine of the unified SA core
-(``repro.core.annealing``): the Eq. 3-6 mapping score is re-expressed as a
-pure function of a flat permutation device array, the move-propose /
-score / accept loop becomes a ``lax.scan``, and the scan is ``vmap``-ed
-across annealing chains *and* across the same-shape candidate
-configurations — one XLA dispatch advances every chain of every candidate.
+This is the ``backend="jax"`` executor of the one SA driver
+(``repro.core.annealing``; ``"numpy"``, the default, is the other): it
+runs the same ``MovePlan``, iteration-bound.  The Eq. 3-6 mapping score
+is re-expressed as a pure function of a flat permutation device array,
+the move-propose / score / accept loop becomes a ``lax.scan``, and the
+scan is ``vmap``-ed across annealing chains *and* across the same-shape
+candidate configurations — one XLA dispatch advances every chain of
+every candidate.
 
 The score runs in float64 under a scoped ``jax.enable_x64`` and mirrors
 :class:`repro.core.dedication.DedicationEngine` reduction by reduction

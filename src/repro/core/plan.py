@@ -62,10 +62,13 @@ from .simulator import Conf, Workload
 #    produced this plan: warm-start source fingerprint + neighbor
 #    distance; null = a direct cold search), and ``overhead`` grows the
 #    deterministic accepted-move counters ``sa_accepted`` /
-#    ``sa_accepted_to_best`` (the warm-start economy metric).  Any
-#    further change to the serialized shape MUST bump this
-#    (tests/test_plan_golden.py enforces it).
-PLAN_SCHEMA_VERSION = 5
+#    ``sa_accepted_to_best`` (the warm-start economy metric).
+# 6: one SA driver — ``provenance.budget.backend`` is always "numpy" or
+#    "jax" (null, the removed per-candidate driver, no longer exists).
+#    Key paths unchanged.  Any further change to the serialized shape
+#    or to what a field means MUST bump this (tests/test_plan_golden.py
+#    enforces the shape).
+PLAN_SCHEMA_VERSION = 6
 
 
 # ---------------------------------------------------------------------------
@@ -114,24 +117,26 @@ class Budget:
     """SA dedication budget (per candidate, split across chains).
 
     Attributes:
-        sa_seconds / sa_iters: wall-clock / iteration caps per candidate
-            (whichever bites first; use a large ``sa_seconds`` with a small
-            ``sa_iters`` for deterministic, iteration-bound runs).
+        sa_seconds / sa_iters: wall-clock / iteration caps per candidate,
+            each split evenly across its chains (whichever bites first;
+            the wall clock binds the ``"numpy"`` executor only — use a
+            large ``sa_seconds`` with a small ``sa_iters`` for
+            deterministic, iteration-bound runs).
         n_chains: independent SA restarts per candidate, best-of.
         sa_topk: anneal only the ``k`` best pre-scored candidates; the
             rest keep their default mapping (``None`` = anneal every
             survivor).
-        backend: SA execution engine.  ``None`` (default) keeps the
-            historical per-candidate ``anneal``/``anneal_multistart``
-            driver, bit-exact with its regression fixtures; ``"numpy"`` /
-            ``"jax"`` select the unified :mod:`~repro.core.annealing`
-            core (precomputed :class:`~repro.core.annealing.MovePlan`,
-            exact chain budget split, optional hierarchical island
-            search) executed incrementally on the host or as one vmapped
-            ``lax.scan`` dispatch — the two produce byte-identical plans.
+        backend: which executor runs the one
+            :class:`~repro.core.annealing.MovePlan` of the SA driver
+            (:func:`~repro.core.annealing.dedicate_candidates`):
+            ``"numpy"`` (default) anneals incrementally on the host and
+            honours ``sa_seconds``; ``"jax"`` runs every chain of a shape
+            group as one vmapped ``lax.scan`` dispatch, iteration-bound.
+            The two produce byte-identical plans on CPU while
+            ``sa_seconds`` does not bite.
         hierarchical: island-decomposed search (coarse inter-island
-            arrangement + within-island refinement; unified backends
-            only).  ``None`` = auto: hierarchical at >= 2048 GPUs.
+            arrangement + within-island refinement).  ``None`` = auto:
+            hierarchical at >= 2048 GPUs.
         warm_start: incumbent flat GPU permutation to seed every SA chain
             with (``None`` = cold start from the coarse/identity
             assignment).  Must be a permutation of ``range(n_gpus)``; the
@@ -146,17 +151,16 @@ class Budget:
     sa_iters: int = 8_000
     n_chains: int = 1
     sa_topk: Optional[int] = None
-    backend: Optional[str] = None
+    backend: str = "numpy"
     hierarchical: Optional[bool] = None
     warm_start: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         if self.sa_seconds <= 0 or self.sa_iters < 1 or self.n_chains < 1:
             raise ValueError("sa_seconds/sa_iters/n_chains must be positive")
-        if self.backend not in (None, "numpy", "jax"):
+        if self.backend not in ("numpy", "jax"):
             raise ValueError(
-                f"backend must be None, 'numpy' or 'jax', "
-                f"got {self.backend!r}")
+                f"backend must be 'numpy' or 'jax', got {self.backend!r}")
         if self.hierarchical is not None \
                 and not isinstance(self.hierarchical, bool):
             raise ValueError("hierarchical must be None or a bool")

@@ -23,10 +23,10 @@ per-candidate loop:
    budget concentrates where it matters; the rest keep their default
    mapping and pre-scored latency.
 
-The SA stage uses the incremental :class:`~repro.core.dedication.
-DedicationEngine`; its permutation-position index tensors depend only on the
-(pp, tp, cp, dp) shape, so they are built once per shape and shared across
-every microbatch variant of that shape.
+Stage 5 is :func:`~repro.core.annealing.dedicate_candidates`, which groups
+the candidates by parallelism shape: the NumPy executor shares one
+permutation-position index per shape across its microbatch variants, the
+JAX executor anneals a whole shape group in one vmapped dispatch.
 
 Stages 1-4 are reified as :class:`BatchSearchContext` so *near-identical
 requests* (same workload + cluster + space shape, different microbatch
@@ -45,14 +45,14 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from .. import obs
 from .cluster import ClusterSpec
-from .dedication import (DedicationEngine, GroupIndex, PairCache, SAResult,
-                         anneal, anneal_multistart)
+from .annealing import dedicate_candidates
+from .dedication import SAResult
 from .latency import default_mapping_latencies
 from .memory import MemoryEstimator, enumerate_confs, ground_truth_memory
 from .partition import Partition
@@ -341,30 +341,13 @@ class BatchSearchContext:
         Filters the shared union enumeration by the request's own
         microbatch predicates (order-preserving — the filtered list is
         exactly what the request would have enumerated standalone), then
-        indexes the shared predictions/profiles/pre-scores and anneals.
-        ``budget.warm_start``, when set, must be a permutation of the
-        cluster's GPU ids; it seeds every SA chain with that incumbent
-        mapping (both the unified NumPy/JAX backends and the legacy
-        per-candidate path).
+        indexes the shared predictions/profiles/pre-scores and anneals
+        them with :func:`~repro.core.annealing.dedicate_candidates`.
         """
         t0 = time.perf_counter()
         self._check(req)
         space, budget, seed = req.space, req.budget, req.seed
-        sa_seconds, sa_iters = budget.sa_seconds, budget.sa_iters
-        n_chains, sa_topk = budget.n_chains, budget.sa_topk
         spec, bw = self.spec, self.bw
-
-        warm_perm: Optional[np.ndarray] = None
-        warm = getattr(budget, "warm_start", None)
-        if warm is not None:
-            warm_perm = np.asarray(warm, dtype=np.int64)
-            n = spec.n_gpus
-            if (warm_perm.shape != (n,)
-                    or not np.array_equal(np.sort(warm_perm),
-                                          np.arange(n))):
-                raise ValueError(
-                    f"budget.warm_start must be a permutation of the {n} "
-                    f"cluster GPU ids, got shape {warm_perm.shape}")
 
         # per-request view of the shared stages
         idx = [i for i, c in enumerate(self._confs)
@@ -383,72 +366,24 @@ class BatchSearchContext:
         # each idle gap after the outermost span over it, so one around
         # the stage would hide the SA driver's own spans.
         sa_time = 0.0
-        cands: List[Candidate] = []
+        sa_res: Dict[int, SAResult] = {}
         if dedicate and survivors:
             ts = time.perf_counter()
+            sa_topk = budget.sa_topk
             if sa_topk is None or sa_topk >= len(survivors):
-                sa_set = set(range(len(survivors)))
+                sa_idx = list(range(len(survivors)))
             else:
                 order = np.argsort(base_lat, kind="stable")
-                sa_set = set(int(i) for i in order[:max(sa_topk, 0)])
-            if budget.backend is not None:
-                # unified backend-selectable core: one MovePlan executed
-                # by the incremental NumPy engine or the vmapped JAX
-                # annealer (byte-identical results); candidates batched
-                # per shape; warm_start is read off the budget inside
-                from .annealing import dedicate_candidates
-                sa_res = dedicate_candidates(survivors, profiles,
-                                             sorted(sa_set), bw, spec,
-                                             budget, seed)
-                for i, conf in enumerate(survivors):
-                    if i in sa_res:
-                        cands.append(Candidate(conf, sa_res[i].mapping,
-                                               sa_res[i].latency,
-                                               float(mem_preds[i]),
-                                               sa=sa_res[i]))
-                    else:
-                        cands.append(Candidate(conf, default_mapping(conf),
-                                               float(base_lat[i]),
-                                               float(mem_preds[i])))
-                survivors = []        # handled; skip the legacy loop
-            index_cache: Dict[Tuple[int, int, int, int], GroupIndex] = {}
-            pair_cache: Optional[PairCache] = None
-            for i, (conf, prof) in enumerate(zip(survivors, profiles)):
-                if i not in sa_set:
-                    cands.append(Candidate(conf, default_mapping(conf),
-                                           float(base_lat[i]),
-                                           float(mem_preds[i])))
-                    continue
-                shape = (conf.pp, conf.tp, conf.cp, conf.dp)
-                gidx = index_cache.get(shape)
-                if gidx is None:
-                    gidx = index_cache[shape] = GroupIndex.build(conf)
-                if pair_cache is None:
-                    # the O(G^2) pair matrices depend only on (bw, spec)
-                    # — one build serves every annealed candidate
-                    pair_cache = PairCache.build(bw, spec.gpus_per_node)
-                engine = DedicationEngine(conf, bw, prof, spec, index=gidx,
-                                          pairs=pair_cache)
-                if n_chains > 1:
-                    res = anneal_multistart(conf, bw, prof, spec,
-                                            n_chains=n_chains,
-                                            time_limit_s=sa_seconds,
-                                            max_iters=sa_iters, seed=seed,
-                                            init_perm=warm_perm,
-                                            engine=engine)
-                else:
-                    res = anneal(conf, bw, prof, spec,
-                                 time_limit_s=sa_seconds,
-                                 max_iters=sa_iters, seed=seed,
-                                 init_perm=warm_perm, engine=engine)
-                cands.append(Candidate(conf, res.mapping, res.latency,
-                                       float(mem_preds[i]), sa=res))
+                sa_idx = sorted(int(i) for i in order[:max(sa_topk, 0)])
+            sa_res = dedicate_candidates(survivors, profiles, sa_idx, bw,
+                                         spec, budget, seed)
             sa_time = time.perf_counter() - ts
-        else:
-            for i, conf in enumerate(survivors):
-                cands.append(Candidate(conf, default_mapping(conf),
-                                       float(base_lat[i]),
-                                       float(mem_preds[i])))
+        cands = [Candidate(conf, sa_res[i].mapping, sa_res[i].latency,
+                           float(mem_preds[i]), sa=sa_res[i])
+                 if i in sa_res else
+                 Candidate(conf, default_mapping(conf), float(base_lat[i]),
+                           float(mem_preds[i]))
+                 for i, conf in enumerate(survivors)]
 
         # record partition + schedule provenance on every candidate
         for c in cands:

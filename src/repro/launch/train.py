@@ -74,8 +74,7 @@ def plan_for_devices(cfg, seq_len: int, global_batch: int, *, seed: int = 0,
         spec = live_host_spec(bw, gpu_mem=limit)
         req = PlanRequest(workload=Workload(cfg, seq_len, global_batch),
                           spec=spec, space=SearchSpace(max_cp=1, max_vpp=1),
-                          budget=Budget(sa_seconds=60.0, sa_iters=2000,
-                                        backend="numpy"),
+                          budget=Budget(sa_seconds=60.0, sa_iters=2000),
                           seed=seed)
         # one device has one mapping: rank configurations, anneal nothing
         strategy = (PipetteStrategy() if len(devices) > 1

@@ -310,14 +310,10 @@ class ReplanPolicy:
     from the incumbent mapping projected onto the survivors and selects
     by ``latency + migration_weight * downtime``.  ``warm=False`` is the
     from-scratch baseline: cold SA, pure-fastest selection, whatever
-    resharding that implies.
-
-    ``backend`` defaults to the unified ``"numpy"`` SA core rather than
-    the legacy per-candidate driver because the unified core *guards* its
-    warm seed — the incumbent permutation is used only when it scores
-    better than the coarse init — so warm-starting can shift but never
-    degrade a candidate's SA outcome, keeping the cross-candidate
-    ranking honest.
+    resharding that implies.  The SA driver *guards* the warm seed — the
+    incumbent permutation is used only when it scores better than the
+    coarse init — so warm-starting can shift but never degrade a
+    candidate's SA outcome, keeping the cross-candidate ranking honest.
     """
     name: str
     warm: bool
@@ -326,7 +322,6 @@ class ReplanPolicy:
     sa_iters: int = 400
     partition: str = "uniform"
     max_vpp: int = 1
-    backend: str = "numpy"
     seed: int = 0
 
 
@@ -452,7 +447,7 @@ def simulate_churn(w: Workload, spec: ClusterSpec, trace: ChurnTrace,
             survivors=survivors if policy.warm else None,
             sa_seconds=policy.sa_seconds, sa_iters=policy.sa_iters,
             partition=policy.partition, max_vpp=policy.max_vpp,
-            backend=policy.backend, seed=policy.seed + plan_idx)
+            seed=policy.seed + plan_idx)
         cand = ep.chosen if ep.chosen is not None else ep.plan.ranked[0]
         # the incumbent artifact for the *next* replan reflects the
         # candidate actually going live, not necessarily plan.best

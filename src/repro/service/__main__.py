@@ -182,7 +182,7 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--sa-iters", type=int, default=200)
     s.add_argument("--n-chains", type=int, default=1)
     s.add_argument("--sa-topk", type=int, default=None)
-    s.add_argument("--backend", default=None,
+    s.add_argument("--backend", default=Budget.backend,
                    choices=["numpy", "jax"])
     s.add_argument("-o", "--output", default=None,
                    help="write the plan JSON here (default: stdout)")

@@ -1,4 +1,4 @@
-"""Regenerate ``golden_plan_v5.json`` — the checked-in Plan JSON fixture.
+"""Regenerate ``golden_plan_v6.json`` — the checked-in Plan JSON fixture.
 
 The fixture is the serialized Plan of a fixed, iteration-bound (fully
 deterministic) Pipette search on the mixed A100/V100 16x1 cluster, so it
@@ -15,7 +15,7 @@ from repro.core import (Budget, Planner, PlanRequest, PipetteStrategy,
 from repro.core.cluster import A100_TIER, V100_TIER, mixed_fleet_spec
 from repro.models.config import ModelConfig
 
-OUT = pathlib.Path(__file__).parent / "golden_plan_v5.json"
+OUT = pathlib.Path(__file__).parent / "golden_plan_v6.json"
 
 GPT = ModelConfig(name="g12", family="dense", n_layers=12, d_model=1024,
                   n_heads=16, n_kv_heads=16, d_ff=4096, vocab_size=32000)

@@ -12,7 +12,8 @@ from repro.core import (MID_RANGE, Conf, Workload, build_profile, configure,
                         pipette_latency_ref, profile_bandwidth,
                         true_bandwidth_matrix)
 from repro.core.cluster import A100_TIER, V100_TIER
-from repro.core.dedication import (DedicationEngine, GroupIndex, _move_span,
+from repro.core.annealing import _move_numpy
+from repro.core.dedication import (DedicationEngine, GroupIndex,
                                    perm_to_mapping)
 from repro.core.latency import _t_dp_first_stage, default_mapping_latencies
 from repro.core.simulator import ProfileCache, mapping4
@@ -123,8 +124,10 @@ def test_cp_engine_score_and_delta_match_latency():
         perm = rng.permutation(conf.n_gpus)
         assert eng.score(perm) == pipette_latency(
             conf, perm_to_mapping(perm, conf), bw, prof, SPEC)
+        n = conf.n_gpus
         for _ in range(60):
-            cand, touched = _move_span(perm, rng)
+            kind, pa, pb = (int(v) for v in rng.integers((3, n, n - 1)))
+            cand, touched = _move_numpy(perm, kind, pa, pb + (pb >= pa))
             val, pending = eng.propose(cand, touched)
             want = pipette_latency(conf, perm_to_mapping(cand, conf), bw,
                                    prof, SPEC)

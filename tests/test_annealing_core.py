@@ -1,16 +1,20 @@
-"""Unified SA core: budget split, MovePlan, move semantics, chain parity.
+"""The SA driver: budget split, wall clock, MovePlan, move semantics,
+chain parity.
 
-Covers the `anneal_multistart` budget-split fix (exact divmod totals,
-zero-iteration chains, `time_limit_s=0` score-only behavior), the
+Covers ``dedicate_candidates``' chain budget split (exact divmod totals,
+zero-iteration chains) and the NumPy executor's wall-clock rule, the
 host-precomputed :class:`~repro.core.annealing.MovePlan` (determinism,
 bounds, exact split, single-island degeneration), the shared move
 semantics of the NumPy and JAX executors, and chain-for-chain bit parity
 between the two backends at the engine level."""
+import time
+
 import numpy as np
 import pytest
 
-from repro.core import (ClusterSpec, Conf, DedicationEngine, Workload,
-                        anneal_multistart, build_profile, make_move_plan,
+from repro.core import (MID_RANGE, Budget, ClusterSpec, Conf,
+                        DedicationEngine, Workload, build_profile,
+                        dedicate_candidates, make_move_plan,
                         perm_to_mapping, profile_bandwidth)
 from repro.core.annealing import (_ALPHA, _move_numpy, _run_chain_numpy,
                                   build_islands, coarse_assign,
@@ -29,45 +33,59 @@ def setup():
     return bw, prof
 
 
+def _dedicate(bw, prof, seed, **budget):
+    return dedicate_candidates([CONF], [prof], [0], bw, SPEC,
+                               Budget(**budget), seed)[0]
+
+
 # ---------------------------------------------------------------------------
-# anneal_multistart budget split (the satellite fix)
+# the driver's budget split and wall clock
 # ---------------------------------------------------------------------------
 
 def test_multistart_iters_exact_when_chains_exceed_budget(setup):
-    """n_chains > max_iters must NOT run n_chains extra iterations (the
+    """n_chains > sa_iters must NOT run n_chains extra iterations (the
     historical ``max(1, max_iters // n_chains)`` bug)."""
     bw, prof = setup
-    res = anneal_multistart(CONF, bw, prof, SPEC, n_chains=5,
-                            time_limit_s=60.0, max_iters=2, seed=0)
+    res = _dedicate(bw, prof, 0, n_chains=5, sa_seconds=60.0, sa_iters=2)
     assert res.iters == 2
+    assert len(res.chain_latencies) == 5
 
 
 @pytest.mark.parametrize("max_iters,n_chains", [(7, 3), (1, 4), (60, 4),
                                                 (9, 9), (10, 1)])
 def test_multistart_iters_sum_exactly(setup, max_iters, n_chains):
     bw, prof = setup
-    res = anneal_multistart(CONF, bw, prof, SPEC, n_chains=n_chains,
-                            time_limit_s=60.0, max_iters=max_iters, seed=3)
+    res = _dedicate(bw, prof, 3, n_chains=n_chains, sa_seconds=60.0,
+                    sa_iters=max_iters)
     assert res.iters == max_iters
 
 
-def test_multistart_zero_time_limit_is_score_only(setup):
-    """time_limit_s=0: every chain gets a zero wall-clock budget — defined
-    as score-only, returning the initial permutation untouched."""
-    bw, prof = setup
-    res = anneal_multistart(CONF, bw, prof, SPEC, n_chains=3,
-                            time_limit_s=0.0, max_iters=100, seed=0)
-    assert res.iters == 0
-    assert np.array_equal(res.perm, np.arange(CONF.n_gpus))
-    eng = DedicationEngine(CONF, bw, prof, SPEC)
-    assert res.latency == eng.score(np.arange(CONF.n_gpus))
+def test_multistart_zero_time_limit_is_score_only():
+    """The NumPy executor stops each chain after ``sa_seconds / n_chains``
+    of wall clock: a small ``sa_seconds`` against a million moves returns
+    within a few multiples of ``sa_seconds``, having run fewer moves than
+    ``sa_iters`` (and counting exactly the moves it ran)."""
+    spec = MID_RANGE
+    conf = Conf(4, 8, 4, 2, 256)
+    bw, _ = profile_bandwidth(spec)
+    prof = build_profile(Workload(GPT_3_1B, 2048, 256), spec, conf)
+    budget = Budget(sa_seconds=0.25, sa_iters=1_000_000, n_chains=3)
+    t0 = time.perf_counter()
+    res = dedicate_candidates([conf], [prof], [0], bw, spec, budget, 0)[0]
+    wall = time.perf_counter() - t0
+    assert 0 < res.iters < budget.sa_iters
+    assert res.seconds < 4 * budget.sa_seconds
+    assert wall < 8 * budget.sa_seconds
+    eng = DedicationEngine(conf, bw, prof, spec)
+    assert res.latency == eng.score(res.perm)
+    assert res.latency <= eng.score(np.arange(conf.n_gpus))
 
 
 def test_multistart_deterministic_after_fix(setup):
     bw, prof = setup
-    kw = dict(n_chains=3, time_limit_s=60.0, max_iters=50, seed=8)
-    a = anneal_multistart(CONF, bw, prof, SPEC, **kw)
-    b = anneal_multistart(CONF, bw, prof, SPEC, **kw)
+    kw = dict(n_chains=3, sa_seconds=60.0, sa_iters=50)
+    a = _dedicate(bw, prof, 8, **kw)
+    b = _dedicate(bw, prof, 8, **kw)
     assert a.latency == b.latency and np.array_equal(a.perm, b.perm)
     assert a.chain_latencies == b.chain_latencies
 

@@ -130,12 +130,17 @@ def test_pallas_interpret_matches_ref_kernels(monkeypatch):
 
 
 def test_legacy_default_backend_differs_only_in_budget_fields():
-    """backend=None keeps the historical stage-5 loop: it must still
-    produce a *valid* plan for the same request (pinned elsewhere by the
-    hex-float regression suite), and the new budget knobs default null."""
+    """A Budget that names no backend runs the NumPy executor: its plan
+    is byte-identical to the explicit ``backend="numpy"`` plan, records
+    ``"numpy"``, and leaves ``hierarchical`` on auto (null)."""
     bw, _ = profile_bandwidth(MIXED)
-    plan = Planner(PipetteStrategy()).plan(_req(MIXED, None), bw)
+    req = _req(MIXED, "numpy")
+    default = dataclasses.replace(req, budget=Budget(
+        sa_seconds=60.0, sa_iters=40, n_chains=2, sa_topk=2))
+    plan = Planner(PipetteStrategy()).plan(default, bw)
+    assert plan.to_json() == Planner(PipetteStrategy()).plan(
+        req, bw).to_json()
     d = plan.to_json_dict()
-    assert d["provenance"]["budget"]["backend"] is None
+    assert d["provenance"]["budget"]["backend"] == "numpy"
     assert d["provenance"]["budget"]["hierarchical"] is None
     assert plan.feasible

@@ -3,9 +3,9 @@ simulated clusters — the paper's headline claims at test scale."""
 import numpy as np
 import pytest
 
-from repro.core import (MID_RANGE, Conf, DedicationEngine, GroupIndex,
-                        Workload, amp_configure, amp_latency, anneal,
-                        build_profile, configure, default_mapping,
+from repro.core import (MID_RANGE, Budget, Conf, Workload, amp_configure,
+                        amp_latency, build_profile, configure,
+                        dedicate_candidates, default_mapping,
                         enumerate_confs, fit_memory_estimator,
                         ground_truth_memory, measure, mlm_configure,
                         pipette_latency, profile_bandwidth,
@@ -111,16 +111,17 @@ def test_sa_topk_matches_exhaustive_best(bw):
 
 
 def test_ranked_order_matches_prerefactor_reference(bw):
-    """The staged pipeline must rank exactly like the pre-refactor
-    per-candidate loop (same confs, bit-equal latencies) for a fixed seed,
-    with and without SA dedication."""
+    """The staged pipeline must rank exactly like a per-candidate loop
+    (same confs, bit-equal latencies) for a fixed seed, with and without
+    SA dedication: annealing one candidate at a time gives the same
+    result as annealing them batched by shape."""
     _, bw_meas = bw
     kw = dict(max_micro=4, seed=5)
     res_sa = configure(W, SPEC, bw_meas, sa_seconds=60.0, sa_iters=120, **kw)
     res_plain = configure(W, SPEC, bw_meas, dedicate=False, **kw)
 
     cands_sa, cands_plain = [], []
-    index_cache = {}
+    budget = Budget(sa_seconds=60.0, sa_iters=120)
     for conf in enumerate_confs(SPEC.n_gpus, W.bs_global,
                                 n_layers=GPT.n_layers):
         if conf.bs_micro > 4:
@@ -129,13 +130,8 @@ def test_ranked_order_matches_prerefactor_reference(bw):
         m = default_mapping(conf)
         cands_plain.append((conf, pipette_latency(conf, m, bw_meas, prof,
                                                   SPEC)))
-        shape = (conf.pp, conf.tp, conf.dp)
-        idx = index_cache.get(shape)
-        if idx is None:
-            idx = index_cache[shape] = GroupIndex.build(conf)
-        engine = DedicationEngine(conf, bw_meas, prof, SPEC, index=idx)
-        r = anneal(conf, bw_meas, prof, SPEC, time_limit_s=60.0,
-                   max_iters=120, seed=5, engine=engine)
+        r = dedicate_candidates([conf], [prof], [0], bw_meas, SPEC, budget,
+                                5)[0]
         cands_sa.append((conf, r.latency))
     cands_sa.sort(key=lambda t: t[1])
     cands_plain.sort(key=lambda t: t[1])

@@ -4,10 +4,10 @@ cluster."""
 import numpy as np
 import pytest
 
-from repro.core import (MID_RANGE, Conf, Workload, anneal, build_profile,
-                        default_mapping, perm_to_mapping,
-                        true_bandwidth_matrix)
-from repro.core.dedication import _move
+from repro.core import (MID_RANGE, Budget, Conf, Workload, build_profile,
+                        dedicate_candidates, default_mapping,
+                        perm_to_mapping, true_bandwidth_matrix)
+from repro.core.annealing import _move_numpy
 from repro.core.latency import pipette_latency
 from repro.models.config import ModelConfig
 
@@ -29,7 +29,8 @@ def test_moves_preserve_permutation(n, seed, moves):
     rng = np.random.default_rng(seed)
     p = np.arange(n)
     for _ in range(moves):
-        p = _move(p, rng)
+        kind, pa, pb = (int(v) for v in rng.integers((3, n, n - 1)))
+        p, _ = _move_numpy(p, kind, pa, pb + (pb >= pa))
         assert sorted(p.tolist()) == list(range(n))
 
 
@@ -52,8 +53,8 @@ def test_sa_improves_on_heterogeneous_cluster():
     prof = build_profile(w, spec, conf)
     m0 = default_mapping(conf)
     base = pipette_latency(conf, m0, bw, prof, spec)
-    res = anneal(conf, bw, prof, spec, time_limit_s=1.0, max_iters=3000,
-                 seed=1)
+    res = dedicate_candidates([conf], [prof], [0], bw, spec,
+                              Budget(sa_seconds=1.0, sa_iters=3000), 1)[0]
     assert res.latency <= base * (1 + 1e-9)
     # the best-so-far trace is monotone non-increasing
     vals = [v for _, v in res.trace]
@@ -68,8 +69,8 @@ def test_sa_respects_tp_locality():
     conf = Conf(2, 8, 2, 2, 128)
     bw = true_bandwidth_matrix(spec)
     prof = build_profile(w, spec, conf)
-    res = anneal(conf, bw, prof, spec, time_limit_s=1.0, max_iters=4000,
-                 seed=3)
+    res = dedicate_candidates([conf], [prof], [0], bw, spec,
+                              Budget(sa_seconds=1.0, sa_iters=4000), 3)[0]
     for x in range(conf.pp):
         for z in range(conf.dp):
             nodes = {int(res.mapping[x, y, z]) // spec.gpus_per_node

@@ -17,13 +17,14 @@ import pytest
 
 from repro.core import (MIXED_A100_V100, Budget, Conf, DedicationEngine,
                         Planner, PlanRequest, PipetteStrategy, SearchSpace,
-                        Workload, anneal_multistart, build_profile,
-                        configure, default_mapping, pipette_latency,
+                        Workload, build_profile, configure,
+                        dedicate_candidates, default_mapping, pipette_latency,
                         pipette_latency_ref, profile_bandwidth,
                         true_bandwidth_matrix)
 from repro.core.cluster import (A100_TIER, V100_TIER, compute_slowdowns,
                                 mixed_fleet_spec, tier_fingerprint)
-from repro.core.dedication import _move_span, perm_to_mapping
+from repro.core.annealing import _move_numpy
+from repro.core.dedication import perm_to_mapping
 from repro.core.simulator import measure
 from repro.models.config import ModelConfig
 
@@ -57,8 +58,10 @@ def test_engine_matches_model_and_ref_on_tiered_spec(conf):
     rng = np.random.default_rng(11)
     perm = np.arange(conf.n_gpus)
     eng.score(perm)
+    n = conf.n_gpus
     for trial in range(120):
-        cand, touched = _move_span(perm, rng)
+        kind, pa, pb = (int(v) for v in rng.integers((3, n, n - 1)))
+        cand, touched = _move_numpy(perm, kind, pa, pb + (pb >= pa))
         val, pending = eng.propose(cand, touched)
         m = perm_to_mapping(cand, conf)
         assert val == pipette_latency(conf, m, bw, prof, spec)
@@ -115,10 +118,10 @@ def test_compute_aware_beats_blind_in_simulator():
     bw, _ = profile_bandwidth(spec)
     bw_true = true_bandwidth_matrix(spec)
     prof = build_profile(W, spec, conf)
-    kw = dict(n_chains=4, time_limit_s=30.0, max_iters=40_000, seed=0)
-    aware = anneal_multistart(conf, bw, prof, spec, **kw)
-    blind = anneal_multistart(conf, bw, prof, spec, compute_aware=False,
-                              **kw)
+    budget = Budget(n_chains=4, sa_seconds=30.0, sa_iters=40_000)
+    aware = dedicate_candidates([conf], [prof], [0], bw, spec, budget, 0)[0]
+    blind = dedicate_candidates([conf], [prof], [0], bw, spec, budget, 0,
+                                compute_aware=False)[0]
     sim_aware = measure(conf, aware.mapping, W, spec, bw_true, seed=1)
     sim_blind = measure(conf, blind.mapping, W, spec, bw_true, seed=1)
     sim_default = measure(conf, default_mapping(conf), W, spec, bw_true,

@@ -6,7 +6,7 @@ Four contracts:
    degenerates to the legacy ceil-first split on uniform cost vectors;
 2. **bit-exact legacy path** — ``partition="dp"`` on a uniform-cost model
    (and plain 1F1B everywhere) reproduces the historical plan *bytes*, on
-   the legacy driver and both unified SA backends;
+   the default Budget and both SA executors;
 3. **parity** — with a real partition and/or ``vpp > 1``, the latency
    reference, the incremental NumPy engine, and the jitted JAX engine all
    score bit-identically;
@@ -145,8 +145,8 @@ def test_dp_mode_on_uniform_model_reproduces_legacy_plan_bytes(backend):
     spec = ClusterSpec(name="t", n_nodes=4, gpus_per_node=4)
     w = Workload(DENSE, 128, 64)
     bw, _ = profile_bandwidth(spec)
-    budget = Budget(sa_seconds=60.0, sa_iters=40, sa_topk=2,
-                    backend=backend)
+    knobs = {} if backend is None else {"backend": backend}   # None: default
+    budget = Budget(sa_seconds=60.0, sa_iters=40, sa_topk=2, **knobs)
     base = Planner(PipetteStrategy()).plan(
         PlanRequest(w, spec, SearchSpace(), budget, seed=3), bw)
     dp = Planner(PipetteStrategy()).plan(

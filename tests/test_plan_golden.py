@@ -1,8 +1,8 @@
-"""Golden-file contract for the serialized Plan schema (version 5).
+"""Golden-file contract for the serialized Plan schema (version 6).
 
 Three locks:
 
-1. the checked-in fixture (``tests/data/golden_plan_v5.json``) loads and
+1. the checked-in fixture (``tests/data/golden_plan_v6.json``) loads and
    re-serializes **byte-for-byte** — the wire format cannot drift silently;
 2. regenerating the same request live reproduces the fixture bytes —
    plans are deterministic artifacts, not process-local snapshots;
@@ -18,12 +18,14 @@ import pytest
 from repro.core import Plan, PlanLoadError, profile_bandwidth
 from repro.core.plan import PLAN_SCHEMA_VERSION
 
-GOLDEN = Path(__file__).parent / "data" / "golden_plan_v5.json"
+GOLDEN = Path(__file__).parent / "data" / "golden_plan_v6.json"
 
-#: Every key path of the version-5 schema.  ``[]`` marks list elements.
+#: Every key path of the version-6 schema (the paths of version 5; 6
+#: changed what ``provenance.budget.backend`` may hold).  ``[]`` marks
+#: list elements.
 #: CHANGING THIS SET == CHANGING THE WIRE FORMAT: bump PLAN_SCHEMA_VERSION,
 #: regenerate the fixture, and rename it (golden_plan_v<N>.json).
-SCHEMA_V5_PATHS = frozenset({
+SCHEMA_V6_PATHS = frozenset({
     "best.conf.bs_global", "best.conf.bs_micro", "best.conf.cp",
     "best.conf.dp", "best.conf.pp", "best.conf.tp", "best.conf.vpp",
     "best.latency",
@@ -78,8 +80,9 @@ def test_golden_plan_loads_and_roundtrips_byte_for_byte():
     tiers = plan.provenance.tiers
     assert tiers is not None and len(tiers["digest"]) == 64
     assert {t["name"] for t in tiers["tiers"]} == {"a100", "v100"}
-    # the v3 additions: backend selection is recorded (null = legacy SA)
-    assert plan.provenance.budget.backend is None
+    # the v3 additions, as v6 reads them: the executor that ran (the
+    # Budget default) and hierarchical search left on auto
+    assert plan.provenance.budget.backend == "numpy"
     assert plan.provenance.budget.hierarchical is None
     # the v4 additions: partition/schedule provenance (uniform search →
     # no partition, plain 1F1B) and the vpp degree on every conf
@@ -108,22 +111,22 @@ def test_golden_plan_reproduced_live_byte_for_byte(tmp_path):
 
 def test_schema_version_must_bump_on_shape_change():
     live = _paths(json.loads(GOLDEN.read_text()))
-    if PLAN_SCHEMA_VERSION == 5:
-        assert live == SCHEMA_V5_PATHS, (
+    if PLAN_SCHEMA_VERSION == 6:
+        assert live == SCHEMA_V6_PATHS, (
             "the serialized Plan shape changed but PLAN_SCHEMA_VERSION is "
-            "still 5 — bump it, regenerate tests/data/golden_plan_v5.json "
-            "under the new name, and update SCHEMA_V5_PATHS\n"
-            f"added: {sorted(live - SCHEMA_V5_PATHS)}\n"
-            f"removed: {sorted(SCHEMA_V5_PATHS - live)}")
+            "still 6 — bump it, regenerate tests/data/golden_plan_v6.json "
+            "under the new name, and update SCHEMA_V6_PATHS\n"
+            f"added: {sorted(live - SCHEMA_V6_PATHS)}\n"
+            f"removed: {sorted(SCHEMA_V6_PATHS - live)}")
     else:
         pytest.fail(
-            "PLAN_SCHEMA_VERSION moved past 5: retire this guard by "
+            "PLAN_SCHEMA_VERSION moved past 6: retire this guard by "
             "pinning the new shape and fixture (see gen_golden_plan.py)")
 
 
 def test_loader_rejects_other_schema_versions():
     d = json.loads(GOLDEN.read_text())
-    for bad in (1, 2, 3, 4, PLAN_SCHEMA_VERSION + 1, None):
+    for bad in (1, 2, 3, 4, 5, PLAN_SCHEMA_VERSION + 1, None):
         d["version"] = bad
         with pytest.raises(PlanLoadError, match="schema version"):
             Plan.from_json_dict(d)

@@ -12,11 +12,11 @@ from pathlib import Path
 import pytest
 
 from repro.analysis import verify_plan_dict, verify_plan_file
-from repro.core import profile_bandwidth
+from repro.core import profile_bandwidth, uniform_partition
 from repro.core.cluster import A100_TIER, V100_TIER, mixed_fleet_spec
 
 TESTS = Path(__file__).resolve().parent
-GOLDEN = TESTS / "data" / "golden_plan_v5.json"
+GOLDEN = TESTS / "data" / "golden_plan_v6.json"
 
 # the live spec the golden fixture was generated against
 # (tests/data/gen_golden_plan.py)
@@ -114,7 +114,7 @@ def test_oom_conf_flagged(golden):
 
 
 def test_unschedulable_pipeline(golden):
-    # golden best is pp=8; bs_micro=4 gives n_mb = 32/(4*dp) < pp
+    # golden best is pp=4, dp=4; bs_micro=4 gives n_mb = 32/(4*dp) < pp
     def fn(m):
         m["best"]["conf"]["bs_micro"] = 4
     assert "PLN003" in _errors(_mutate(golden, fn))
@@ -162,10 +162,10 @@ def test_schedule_vpp_inconsistency(golden):
 
 
 def _with_partition(m):
-    """Attach a valid uniform partition to the golden best (pp=8, 12
-    layers → ceil-first boundaries)."""
-    m["best"]["partition"] = {
-        "n_layers": 12, "boundaries": [2, 4, 6, 8, 9, 10, 11, 12]}
+    """Attach a valid uniform partition to the golden best (12 layers →
+    ceil-first boundaries over its pp stages)."""
+    m["best"]["partition"] = uniform_partition(
+        12, m["best"]["conf"]["pp"]).to_json_dict()
 
 
 def test_valid_partition_passes(golden):
@@ -176,7 +176,8 @@ def test_valid_partition_passes(golden):
 def test_partition_boundaries_not_increasing(golden):
     def fn(m):
         _with_partition(m)
-        m["best"]["partition"]["boundaries"][3] = 6   # ties the previous
+        b = m["best"]["partition"]["boundaries"]
+        b[-2] = b[-3]                                  # ties the previous
     assert "PLN009" in _errors(_mutate(golden, fn))
 
 
@@ -190,7 +191,7 @@ def test_partition_does_not_cover_all_layers(golden):
 def test_partition_chunk_count_mismatch(golden):
     def fn(m):
         _with_partition(m)
-        del m["best"]["partition"]["boundaries"][0]    # 7 chunks, pp=8
+        del m["best"]["partition"]["boundaries"][0]    # pp - 1 chunks
     assert "PLN009" in _errors(_mutate(golden, fn))
 
 

@@ -358,6 +358,16 @@ def test_request_dataclasses_validate_and_freeze():
     assert req.space == SearchSpace() and req.budget == Budget()
 
 
+def test_budget_backend_is_numpy_or_jax():
+    """One SA driver with two executors: the NumPy one is the default,
+    and no other value (``None`` included) names an executor."""
+    assert Budget().backend == "numpy"
+    assert Budget(backend="jax").backend == "jax"
+    for bad in (None, "legacy", ""):
+        with pytest.raises(ValueError, match="backend"):
+            Budget(backend=bad)
+
+
 def test_conf_roundtrip_via_plan_schema():
     from repro.core.plan import _conf_in, _conf_out
     for conf in (Conf(2, 2, 2, 2, 64), Conf(2, 2, 1, 2, 32, cp=2),

@@ -293,13 +293,15 @@ def test_run_search_rejects_a_wrong_sized_warm_start(bw):
 def test_warm_start_is_never_worse_and_spends_fewer_accepted_moves(backend):
     """The acceptance gate: seeded from a cached neighbor's incumbent, SA
     reaches a plan at least as good as the cold search's while accepting
-    strictly fewer improving moves (or landing on the identical best)."""
+    strictly fewer improving moves (or landing on the identical best).
+    ``None`` leaves the backend to the Budget default."""
     spec = MID_RANGE.with_nodes(2)      # heterogeneous enough that SA works
     bw2 = profile_bandwidth(spec)[0]
+    knobs = {} if backend is None else {"backend": backend}
     seed_req = PlanRequest(
         workload=W, spec=spec, space=SearchSpace(max_micro=2),
-        budget=Budget(sa_seconds=60.0, sa_iters=80, sa_topk=2,
-                      backend=backend), seed=7)
+        budget=Budget(sa_seconds=60.0, sa_iters=80, sa_topk=2, **knobs),
+        seed=7)
     incumbent = run_search(seed_req, bw2)
     perm = tuple(int(x) for x in mapping_to_perm(incumbent.best.mapping))
 
@@ -314,11 +316,10 @@ def test_warm_start_is_never_worse_and_spends_fewer_accepted_moves(backend):
                  and np.array_equal(warm.best.mapping, cold.best.mapping))
     assert (warm.overhead.sa_accepted_to_best
             < cold.overhead.sa_accepted_to_best) or same_best
-    if backend is None:
-        # pinned: the gate is non-vacuous for the legacy engine — cold SA
-        # does improve on its init here, the warm incumbent needs no moves
-        assert cold.overhead.sa_accepted_to_best > 0
-        assert warm.overhead.sa_accepted_to_best == 0
+    # pinned: the gate is non-vacuous — cold SA does improve on its
+    # init here, the warm incumbent needs no moves
+    assert cold.overhead.sa_accepted_to_best > 0
+    assert warm.overhead.sa_accepted_to_best == 0
 
 
 def test_warm_started_plan_records_the_budget_and_lineage(bw, cold_plan):

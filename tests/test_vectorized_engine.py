@@ -6,13 +6,14 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from repro.core import (MID_RANGE, ClusterSpec, Conf, Workload, anneal,
-                        anneal_multistart, build_profile, dp_allreduce_times,
-                        dp_allreduce_times_ref, mixed_fleet_spec,
-                        pipette_latency, pipette_latency_ref,
-                        true_bandwidth_matrix)
+from repro.core import (MID_RANGE, Budget, ClusterSpec, Conf, Workload,
+                        build_profile, dedicate_candidates,
+                        dp_allreduce_times, dp_allreduce_times_ref,
+                        make_move_plan, mixed_fleet_spec, pipette_latency,
+                        pipette_latency_ref, true_bandwidth_matrix)
+from repro.core.annealing import _ALPHA, _move_numpy, _run_chain_numpy
 from repro.core.cluster import A100_TIER, V100_TIER
-from repro.core.dedication import DedicationEngine, GroupIndex, _move_span, \
+from repro.core.dedication import DedicationEngine, GroupIndex, \
     perm_to_mapping
 from repro.core.simulator import hier_allreduce_batch, mapping4
 from repro.models.config import ModelConfig
@@ -173,8 +174,10 @@ def test_engine_delta_scoring_matches_full_rescore():
         eng = DedicationEngine(conf, bw, prof, spec)
         perm = rng.permutation(conf.n_gpus)
         eng.score(perm)
+        n = conf.n_gpus
         for _ in range(50):
-            cand, touched = _move_span(perm, rng)
+            kind, pa, pb = (int(v) for v in rng.integers((3, n, n - 1)))
+            cand, touched = _move_numpy(perm, kind, pa, pb + (pb >= pa))
             val, pending = eng.propose(cand, touched)
             want = pipette_latency(conf, perm_to_mapping(cand, conf), bw,
                                    prof, spec)
@@ -205,23 +208,39 @@ def test_group_index_shared_across_microbatch_variants():
 
 
 def test_engine_anneal_matches_generic_objective_path():
-    """The engine-driven anneal walks the exact same trajectory as the
-    generic (full-rescore) objective path: same RNG stream + bit-equal
-    scores => identical accept/reject decisions."""
+    """A NumPy-executor chain driven by the incremental engine walks the
+    exact same trajectory as the same chain driven by full re-scores with
+    the pure-Python reference: same MovePlan + bit-equal scores =>
+    identical accept/reject decisions."""
     spec = MID_RANGE.with_nodes(4)
     conf = Conf(4, 4, 2, 2, 128)
     bw = true_bandwidth_matrix(spec)
     prof = build_profile(Workload(GPT, 2048, 128), spec, conf)
 
-    def objective(p):
-        return pipette_latency_ref(conf, perm_to_mapping(p, conf), bw, prof,
-                                   spec)
+    class RefScorer:
+        def score(self, perm):
+            return pipette_latency_ref(conf, perm_to_mapping(perm, conf),
+                                       bw, prof, spec)
 
-    kw = dict(time_limit_s=60.0, max_iters=800, seed=11)
-    r_eng = anneal(conf, bw, prof, spec, **kw)
-    r_gen = anneal(conf, bw, prof, spec, objective=objective, **kw)
-    assert r_eng.latency == r_gen.latency
-    assert np.array_equal(r_eng.perm, r_gen.perm)
+        def propose(self, cand, touched):
+            return self.score(cand), None
+
+        def commit(self, pending):
+            pass
+
+    plan = make_move_plan([conf.n_gpus], 800, 1, seed=11)
+    init, offsets = np.arange(conf.n_gpus), np.zeros(1, np.int64)
+    r_eng = _run_chain_numpy(DedicationEngine(conf, bw, prof, spec), init,
+                             offsets, plan, 0, _ALPHA)
+    r_ref = _run_chain_numpy(RefScorer(), init, offsets, plan, 0, _ALPHA)
+    assert r_eng[0] == r_ref[0]
+    assert np.array_equal(r_eng[1], r_ref[1])
+    assert r_eng[2:] == r_ref[2:]
+
+
+def _dedicate(conf, bw, prof, spec, seed, **budget):
+    return dedicate_candidates([conf], [prof], [0], bw, spec,
+                               Budget(**budget), seed)[0]
 
 
 def test_multistart_deterministic_and_no_worse_than_single():
@@ -229,17 +248,19 @@ def test_multistart_deterministic_and_no_worse_than_single():
     conf = Conf(4, 4, 2, 2, 128)
     bw = true_bandwidth_matrix(spec)
     prof = build_profile(Workload(GPT, 2048, 128), spec, conf)
-    kw = dict(n_chains=3, time_limit_s=60.0, max_iters=900, seed=5)
-    a = anneal_multistart(conf, bw, prof, spec, **kw)
-    b = anneal_multistart(conf, bw, prof, spec, **kw)
+    kw = dict(n_chains=3, sa_seconds=60.0, sa_iters=900)
+    a = _dedicate(conf, bw, prof, spec, 5, **kw)
+    b = _dedicate(conf, bw, prof, spec, 5, **kw)
     assert a.latency == b.latency
     assert np.array_equal(a.perm, b.perm)
     assert a.chain_latencies == b.chain_latencies
     assert len(a.chain_latencies) == 3
     assert a.latency == min(a.chain_latencies)
-    # the winning chain is at least as good as chain 0 alone
-    single = anneal(conf, bw, prof, spec, time_limit_s=60.0, max_iters=300,
-                    seed=5 * 100003)
+    # chain 0 of the three is the single 300-move chain of the same seed,
+    # and the winning chain is at least as good as it
+    single = _dedicate(conf, bw, prof, spec, 5, sa_seconds=60.0,
+                       sa_iters=300)
+    assert a.chain_latencies[0] == single.latency
     assert a.latency <= single.latency
     with pytest.raises(ValueError):
-        anneal_multistart(conf, bw, prof, spec, n_chains=0)
+        _dedicate(conf, bw, prof, spec, 5, n_chains=0)
