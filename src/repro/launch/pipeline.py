@@ -4,9 +4,14 @@ Microbatches rotate through the stages with ``lax.ppermute`` (the JAX
 analogue of Megatron's P2P stage links — the communication pattern
 Pipette's Eq. 5 prices per hop).  Compute follows the GPipe rotation and
 relies on remat for the 1F1B memory profile; the arithmetic is identical
-to the sequential model, which the tests assert exactly.  The Pipette
-(pp, tp, dp) configuration maps onto a ('pipe', 'data', 'model') mesh
-built from the SA worker dedication (launch/mesh.py::mesh_from_mapping).
+to the sequential model, which the tests assert exactly.  The rotation
+carries the layers alone: once it ends, the last stage hands each
+microbatch's final hidden state to the stage that owns it, and every
+stage runs the head and loss for its share of the microbatches, so the
+head's cost is spread evenly over the stages, as the planner's profile
+prices it.  The Pipette (pp, tp, dp) configuration maps onto a ('pipe',
+'data', 'model') mesh built from the SA worker dedication
+(launch/mesh.py::mesh_from_mapping).
 """
 from __future__ import annotations
 
@@ -15,6 +20,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+
+from .. import obs
 
 
 def stage_params_split(layer_params, pp: int):
@@ -36,6 +43,10 @@ def pipeline_loss_fn(embed_fn: Callable, stage_fn: Callable,
               "shared": replicated embed/head/etc}
     tokens_mb, labels_mb: (n_mb, mb, S); with ``data_axis`` set, the mb dim
     is data-parallel-sharded over that axis and the loss is pmean'd.
+
+    Stage ``j`` runs the head for microbatches ``j, j + pp, j + 2 pp, ...``
+    below ``n_mb``: ``ceil(n_mb / pp)`` slots.  Each lowering fires the
+    ``launch.pipeline_head`` layout event with ``slots`` and ``n_mb``.
     """
     pp = mesh.shape[axis]
 
@@ -44,40 +55,44 @@ def pipeline_loss_fn(embed_fn: Callable, stage_fn: Callable,
         n_mb = tokens_mb.shape[0]
         stages_local = jax.tree.map(lambda a: a[0], stages_local)
         sfn = jax.checkpoint(stage_fn) if remat else stage_fn
-        # the head's logits are the largest activation of a tick: recompute
-        # them in the backward pass instead of keeping one set per tick
+        # the head's logits are the largest activation of a step: recompute
+        # them in the backward pass instead of keeping one set per slot
         hfn = jax.checkpoint(head_loss_fn) if remat else head_loss_fn
-        ticks = n_mb + pp - 1
         perm = [(i, (i + 1) % pp) for i in range(pp)]
 
-        def tick(carry, t):
-            state, loss_sum = carry
-            mb_in = jnp.clip(t, 0, n_mb - 1)
-            mb_out = t - (pp - 1)
-            # only stage 0 embeds; only the last stage pays the head/loss
-            # (lax.cond on the stage index — per-device branching inside
-            # shard_map keeps the 15/16 other ranks idle on these)
-            x0 = jax.lax.cond(
+        def tick(state, t):
+            # only stage 0 embeds (lax.cond on the stage index: the other
+            # ranks skip it); every stage runs its layers and hops
+            inp = jax.lax.cond(
                 idx == 0,
-                lambda: embed_fn(shared, tokens_mb[mb_in]).astype(state.dtype),
+                lambda: embed_fn(
+                    shared, tokens_mb[jnp.clip(t, 0, n_mb - 1)]
+                ).astype(state.dtype),
                 lambda: state)
-            inp = jnp.where(idx == 0, x0, state)
             out = sfn(stages_local, inp)
-            lbl = labels_mb[jnp.clip(mb_out, 0, n_mb - 1)]
-            valid = (idx == pp - 1) & (mb_out >= 0) & (mb_out < n_mb)
-            mb_loss = jax.lax.cond(
-                valid,
-                lambda: hfn(shared, out, lbl),
-                lambda: jnp.zeros((), jnp.float32))
-            loss_sum = loss_sum + mb_loss
-            state = jax.lax.ppermute(out, axis, perm)
-            return (state, loss_sum), None
+            return jax.lax.ppermute(out, axis, perm), out
 
         dummy = embed_fn(shared, tokens_mb[0])
-        (state, loss_sum), _ = jax.lax.scan(
-            tick, (jnp.zeros_like(dummy), jnp.zeros((), jnp.float32)),
-            jnp.arange(ticks))
-        total = jax.lax.psum(loss_sum, axis)       # only last stage nonzero
+        _, outs = jax.lax.scan(tick, jnp.zeros_like(dummy),
+                               jnp.arange(n_mb + pp - 1))
+        # on the last stage, ticks pp-1 .. pp-2+n_mb hold the final hidden
+        # states of microbatches 0 .. n_mb-1
+        final = outs[pp - 1:]
+        loss_sum = jnp.zeros((), jnp.float32)
+        for base in range(0, n_mb, pp):
+            # slot ``base // pp``: microbatch base + j goes to stage j, the
+            # last stage keeps its own; a stage past n_mb - 1 gets nothing
+            own = final[min(base + pp - 1, n_mb - 1)]
+            h = jnp.where(idx == pp - 1, own, 0)
+            for j in range(min(pp - 1, n_mb - base)):
+                h = h + jax.lax.ppermute(final[base + j], axis,
+                                         [(pp - 1, j)])
+            k = base + idx
+            loss_sum = loss_sum + jax.lax.cond(
+                k < n_mb,
+                lambda: hfn(shared, h, labels_mb[jnp.minimum(k, n_mb - 1)]),
+                lambda: jnp.zeros((), jnp.float32))
+        total = jax.lax.psum(loss_sum, axis)
         if data_axis:
             total = jax.lax.pmean(total, data_axis)
         return total / n_mb
@@ -90,6 +105,9 @@ def pipeline_loss_fn(embed_fn: Callable, stage_fn: Callable,
         check_vma=False)
 
     def loss(params, tokens_mb, labels_mb):
+        n_mb = tokens_mb.shape[0]
+        obs.count_layout("launch.pipeline_head",
+                         slots=-(-n_mb // pp), n_mb=n_mb)
         return wrapped(params["stages"], params["shared"], tokens_mb,
                        labels_mb)
 

@@ -75,7 +75,7 @@ def loss_fn(params, cfg: ModelConfig, ctx: ShardCtx, batch) -> Tuple[jax.Array, 
 
 def head_loss(params, cfg: ModelConfig, x, labels) -> jax.Array:
     """Final norm, vocab projection and mean CE of a stack's output ``x``
-    (the last pipeline stage's share of :func:`loss_fn`)."""
+    (what each pipeline stage runs for the microbatches it owns)."""
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return cross_entropy(_project_logits(x, params, cfg), labels, cfg)[0]
 

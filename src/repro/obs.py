@@ -10,7 +10,10 @@ the two sinks JAX already has:
   ``record_event("/pipette/trace/<name>", request=<id>)`` each time the
   program traces a jitted function, and an executable-cache hit fires
   ``record_event("/pipette/exe_hit/<name>", request=<id>)`` where it
-  would have traced.  Register a listener
+  would have traced; a layout event fires
+  ``record_event("/pipette/layout/<name>", request=<id>, **numbers)``
+  where a function is lowered with a layout it chose from its input's
+  shapes.  Register a listener
   (``jax.monitoring.register_event_duration_secs_listener``,
   ``register_event_listener``) to export them; listeners run on the
   caller's thread.
@@ -36,6 +39,7 @@ import jax
 SPAN_EVENT = "/pipette/span/"
 TRACE_EVENT = "/pipette/trace/"
 EXE_HIT_EVENT = "/pipette/exe_hit/"
+LAYOUT_EVENT = "/pipette/layout/"
 
 _request = contextvars.ContextVar("pipette_request", default=0)
 _next_request = itertools.count(1)
@@ -86,3 +90,12 @@ def count_exe_hit(name: str) -> None:
     """Fire ``/pipette/exe_hit/<name>``: call it where a compiled
     executable is reused in place of tracing and lowering ``name`` again."""
     jax.monitoring.record_event(EXE_HIT_EVENT + name, request=_request.get())
+
+
+def count_layout(name: str, **numbers: int) -> None:
+    """Fire ``/pipette/layout/<name>`` with ``numbers`` as attributes: call
+    it where a function is traced for lowering, with the layout it chose
+    from its input's shapes, so it fires once a lowering and never per
+    call of the compiled executable."""
+    jax.monitoring.record_event(LAYOUT_EVENT + name, request=_request.get(),
+                                **numbers)

@@ -7,6 +7,8 @@ import sys
 import textwrap
 from pathlib import Path
 
+import pytest
+
 SRC = str(Path(__file__).resolve().parent.parent / "src")
 
 
@@ -84,19 +86,28 @@ def test_uneven_head_seq_sharding_matches():
     assert "OK" in out
 
 
-def test_pipeline_parallel_loss_and_grads_match():
-    out = run_py("""
+@pytest.mark.parametrize("pp,n_mb,data", [
+    (4, 8, 1),      # two head slots a stage
+    (4, 4, 1),      # one
+    (4, 6, 1),      # a partial last slot
+    (4, 2, 1),      # stages without a slot
+    (2, 3, 2),      # microbatch rows split over a data axis
+])
+def test_pipeline_parallel_loss_and_grads_match(pp, n_mb, data):
+    out = run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
         from repro.launch.pipeline import pipeline_loss_fn, stage_params_split
 
-        pp, L, d, V, mb, n_mb, S = 4, 8, 32, 64, 2, 8, 16
-        mesh = jax.make_mesh((pp,), ("pipe",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
+        pp, n_mb, data = {pp}, {n_mb}, {data}
+        L, d, V, mb, S = 8, 32, 64, 2, 16
+        axes = ("pipe", "data")[:1 + (data > 1)]
+        mesh = jax.make_mesh((pp, data)[:len(axes)], axes,
+                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         key = jax.random.PRNGKey(0)
         ks = jax.random.split(key, 4)
-        layers = {"w": jax.random.normal(ks[0], (L, d, d)) * 0.05}
-        shared = {"embed": jax.random.normal(ks[1], (V, d)) * 0.1,
-                  "head": jax.random.normal(ks[2], (d, V)) * 0.1}
+        layers = {{"w": jax.random.normal(ks[0], (L, d, d)) * 0.2}}
+        shared = {{"embed": jax.random.normal(ks[1], (V, d)) * 0.5,
+                  "head": jax.random.normal(ks[2], (d, V)) * 0.5}}
         embed_fn = lambda sh, t: sh["embed"][t]
         def stage_fn(st, x):
             h, _ = jax.lax.scan(lambda c, w: (jnp.tanh(c @ w), None), x, st["w"])
@@ -108,7 +119,7 @@ def test_pipeline_parallel_loss_and_grads_match():
             return jnp.mean(lse - pick)
         toks = jax.random.randint(ks[3], (n_mb, mb, S), 0, V)
         lbls = jax.random.randint(ks[3], (n_mb, mb, S), 0, V)
-        def ref_loss(layers):
+        def ref_loss(layers, shared):
             tot = 0.0
             for i in range(n_mb):
                 h = embed_fn(shared, toks[i])
@@ -116,19 +127,23 @@ def test_pipeline_parallel_loss_and_grads_match():
                                     layers["w"])
                 tot += head_loss_fn(shared, h, lbls[i])
             return tot / n_mb
-        params = {"stages": stage_params_split(layers, pp), "shared": shared}
-        loss_fn = pipeline_loss_fn(embed_fn, stage_fn, head_loss_fn, mesh)
+        params = {{"stages": stage_params_split(layers, pp), "shared": shared}}
+        loss_fn = pipeline_loss_fn(embed_fn, stage_fn, head_loss_fn, mesh,
+                                   data_axis="data" if data > 1 else "")
         with jax.set_mesh(mesh):
             lp = jax.jit(loss_fn)(params, toks, lbls)
             gp = jax.jit(jax.grad(loss_fn))(params, toks, lbls)
-        lr = ref_loss(layers)
+        lr = ref_loss(layers, shared)
         assert abs(float(lp - lr)) < 1e-5
-        gr = jax.grad(ref_loss)(layers)
+        gr, gs = jax.grad(ref_loss, argnums=(0, 1))(layers, shared)
         np.testing.assert_allclose(
             np.asarray(gp["stages"]["w"]).reshape(L, d, d),
             np.asarray(gr["w"]), rtol=3e-4, atol=3e-5)
+        for k in gs:
+            np.testing.assert_allclose(np.asarray(gp["shared"][k]),
+                                       np.asarray(gs[k]), rtol=3e-4, atol=3e-5)
         print("OK")
-    """, devices=4)
+    """, devices=pp * data)
     assert "OK" in out
 
 

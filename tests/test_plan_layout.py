@@ -153,3 +153,52 @@ def test_step_layout_compile_fires_trace_counter_once():
     finally:
         jax.monitoring.unregister_event_listener(on_event)
     assert seen == [obs.TRACE_EVENT + "launch.train_step"]
+
+
+@pytest.mark.parametrize("pp,n_mb", [(4, 4), (4, 6), (2, 1)])
+def test_pipelined_step_lowering_fires_pipeline_head_once(pp, n_mb):
+    """Lowering a pipelined step fires ``launch.pipeline_head`` once, with
+    ``ceil(n_mb / pp)`` head slots a stage; running the compiled step
+    fires nothing."""
+    out = run_py(f"""
+        import math
+        import numpy as np, jax
+        from jax.sharding import Mesh
+        from repro import obs
+        from repro.core import Conf
+        from repro.launch.train import step_layout
+        from repro.models.config import ModelConfig
+        from repro.optim.adamw import AdamW
+
+        pp, n_mb = {pp}, {n_mb}
+        cfg = ModelConfig(name="q", family="dense", n_layers=4, d_model=32,
+                          n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=256,
+                          head_dim=8, qkv_bias=True, tie_embeddings=True,
+                          dtype="float32")
+        opt = AdamW(lr=1e-3)
+        mesh = Mesh(np.asarray(jax.devices()[:pp]).reshape(pp, 1, 1),
+                    ("pipe", "model", "data"))
+        lay = step_layout(cfg, opt, n_micro=n_mb, conf=Conf(pp, 1, 1, 1, n_mb),
+                          mesh=mesh)
+        params = jax.device_put(lay.init(jax.random.PRNGKey(0)), lay.params)
+        state = jax.jit(opt.init, out_shardings=lay.opt_state)(params)
+        tokens = np.zeros((n_mb, 16), np.int32)
+        seen = []
+
+        def on_event(event, **kw):
+            if event.startswith(obs.LAYOUT_EVENT):
+                seen.append((event, kw["slots"], kw["n_mb"]))
+
+        jax.monitoring.register_event_listener(on_event)
+        b = lay.put_batch({{"tokens": tokens, "labels": tokens}})
+        exe = lay.compile(params, state, b)
+        for _ in range(2):
+            params, state, _ = exe(params, state,
+                                   lay.put_batch({{"tokens": tokens,
+                                                  "labels": tokens}}))
+        jax.block_until_ready(params)
+        assert seen == [(obs.LAYOUT_EVENT + "launch.pipeline_head",
+                         math.ceil(n_mb / pp), n_mb)], seen
+        print("OK", seen)
+    """)
+    assert "OK" in out
